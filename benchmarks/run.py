@@ -97,6 +97,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.only and args.only not in MODULES:
         ap.error(f"unknown module {args.only!r} (choose from {MODULES})")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = 0
     results = []

@@ -1898,27 +1898,54 @@ class DeviceColumnStore:
                          scoped=subject is not None)
             return m
 
+    def _match_launch_args(self, exprs: Sequence, now: float,
+                           use_kernel: Optional[bool], with_agg: bool
+                           ) -> Tuple[np.ndarray, dict]:
+        """(operands, static keywords) of the mesh match launch. The
+        Pallas kernel is the default wherever the mesh is made of TPUs."""
+        from ..kernels.policy_scan.ops import _mesh_on_tpu, _program_tuples
+        ops, colidx, operands = compile_programs(exprs, self.catalog.strings,
+                                                 now)
+        ops_t, colidx_t = _program_tuples(ops, colidx)
+        if use_kernel is None:
+            use_kernel = _mesh_on_tpu(self.mesh)
+        return operands, dict(ops_t=ops_t, colidx_t=colidx_t,
+                              size_col=KERNEL_COLUMNS.index("size"),
+                              blocks_col=KERNEL_COLUMNS.index("blocks"),
+                              valid_col=_VALID_COL,
+                              use_kernel=bool(use_kernel), tile=self.tile,
+                              with_agg=with_agg)
+
+    def compiled_match_text(self, exprs: Sequence, now: float,
+                            with_agg: bool = False) -> str:
+        """Compiled text of the launch :meth:`match` makes for these
+        programs over the resident blocks — the program the devices run
+        (a chip check asserts that the Pallas kernel, ``tpu_custom_call``,
+        is in it). Compiles; runs nothing."""
+        from ..kernels.policy_scan.ops import mesh_policy_scan_batch
+        operands, kw = self._match_launch_args(exprs, now, None, with_agg)
+        with self._lock:
+            self.refresh()
+            res = self._resident()
+            if not res:
+                raise PolicyError("no shard group is resident")
+            mesh = self._resident_mesh(res)
+            return mesh_policy_scan_batch.lower(
+                self._assemble(res, mesh), operands, mesh=mesh,
+                **kw).compile().as_text()
+
     def _match_locked(self, exprs: Sequence, now: float,
                       use_kernel: Optional[bool] = None,
                       with_agg: bool = True,
                       subject: Optional[str] = None) -> MeshMatch:
         import jax
         from ..kernels.policy_scan.ops import (_agg_dict,
-                                               merge_agg_partials, _on_tpu,
-                                               _program_tuples,
+                                               merge_agg_partials,
                                                mesh_policy_scan_batch)
-        ops, colidx, operands = compile_programs(exprs, self.catalog.strings,
-                                                 now)
-        ops_t, colidx_t = _program_tuples(ops, colidx)
-        if use_kernel is None:
-            use_kernel = _on_tpu()
+        operands, kw = self._match_launch_args(exprs, now, use_kernel,
+                                               with_agg)
         self.refresh()
         sid = self._resolve_subject(subject)
-        kw = dict(ops_t=ops_t, colidx_t=colidx_t,
-                  size_col=KERNEL_COLUMNS.index("size"),
-                  blocks_col=KERNEL_COLUMNS.index("blocks"),
-                  valid_col=_VALID_COL, use_kernel=bool(use_kernel),
-                  tile=self.tile, with_agg=with_agg)
         res = self._resident()
         mirrors: List[Tuple[np.ndarray, Dict[str, np.ndarray]]] = \
             [(np.zeros(0, np.int64), {})] * self.n_devices
@@ -1970,7 +1997,7 @@ class DeviceColumnStore:
             group_rule[g.gid] = (np.concatenate(rule_parts) if rule_parts
                                  else np.zeros(0, np.int32))
             reval += int(g.segment.n_rows)
-        per_rule = merge_agg_partials(agg_parts, len(ops_t))
+        per_rule = merge_agg_partials(agg_parts, len(kw["ops_t"]))
         return MeshMatch(self, self._epoch, mirrors, group_idx,
                          group_rule, _agg_dict(per_rule[0], per_rule),
                          reval)

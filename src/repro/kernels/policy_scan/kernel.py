@@ -2,29 +2,31 @@
 
 The TPU-native analogue of Robinhood's MySQL table scan (paper C1) fused
 with its on-the-fly aggregation (C6): one pass through the entry table
-evaluates a postfix predicate program and accumulates count / volume /
-spc_used / size-profile histogram — without materializing intermediate
-masks in HBM.
+evaluates a policy's postfix predicate programs and accumulates count /
+volume / spc_used / size-profile histogram — without materializing
+intermediate masks in HBM.
 
 Tiling: the entry table is columnar f32[n_cols, N]; the grid walks row
 tiles of ``tile`` entries (lane-dim aligned to 128). Each grid step holds a
-(n_cols, tile) block in VMEM, evaluates the program on the tile with a
-small in-register stack, emits the tile's match mask, and accumulates the
-aggregate vector into a (1, N_AGG) accumulator block (revisited by every
-grid step — standard Pallas reduction pattern).
+(n_cols, tile) block in VMEM, evaluates every program on the tile, writes
+the tile's (R, tile) mask block and first-match-wins rule attribution, and
+accumulates the per-program aggregates into an (R, N_AGG) accumulator
+block (revisited by every grid step — standard Pallas reduction pattern).
 
-The program (ops/colidx/operands) rides in SMEM-like small blocks; P is
-static (padded with NOPs), so the instruction loop fully unrolls into
-vector selects — no scalar branching on TPU.
+Where the program lives: its *structure* — the opcode and column of every
+instruction (``ops_t``/``colidx_t``) — is static Python data baked into
+the kernel at trace time, so each instruction lowers to the one compare it
+needs on one column row of the VMEM tile, and the AND/OR/NOT stack exists
+only while tracing (no dynamic indexing, no scatter: what Mosaic lowers).
+Only the operand values (``now``-relative thresholds) are data: the (R, P)
+f32 operand table rides whole in SMEM and each compare reads its
+threshold as a scalar, so a policy re-runs with a new ``now`` without
+recompiling. One compilation per policy *shape*.
 
-Two launch shapes share the evaluation loop:
-
-* :func:`policy_scan_pallas` — one program, (N,) mask + fused aggregates;
-* :func:`policy_scan_batch_pallas` — the full (R, P) program batch of a
-  policy (combined criteria + per-rule conditions) in a SINGLE launch,
-  writing the (R, N) mask tile, the fused first-match-wins rule
-  attribution, and per-program size/blocks reductions. One grid walk over
-  the entry table replaces R launches plus two host-side passes.
+:func:`policy_scan_batch_pallas` runs the full (R, P) program batch of a
+policy (combined criteria + per-rule conditions) in a SINGLE launch — one
+grid walk over the entry table replaces R launches plus two host-side
+passes; a single program is the R = 1 case.
 """
 from __future__ import annotations
 
@@ -34,240 +36,139 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ref import N_AGG
+from .ref import N_AGG, OP_AND, OP_LE, OP_NOP, OP_NOT
 
 LANE = 128
 # static python floats (array constants cannot be captured by a kernel)
 _EDGE_VALS = (0.0, 1.0, 32.0, float(1 << 10), float(32 << 10),
               float(1 << 20), float(32 << 20), float(1 << 30),
               float(32 << 30), float(1 << 40))
+_HIST0 = 3                       # agg slot of size-profile bucket 0
+# compare opcodes OP_EQ..OP_LE, in opcode order
+_CMPS = (jnp.equal, jnp.not_equal, jnp.greater, jnp.greater_equal,
+         jnp.less, jnp.less_equal)
+
+Program = Tuple[Tuple[int, ...], ...]
 
 
-def _eval_program_tile(cols, read_instr, n_instr: int, max_stack: int):
-    """Unrolled postfix-program evaluation on a (n_cols, tile) block.
+def _eval_program_tile(cols_ref, ops, colidx, operand):
+    """One static postfix program over the (n_cols, tile) VMEM block.
 
-    ``read_instr(i)`` returns the (op, col, val) scalars of instruction i —
-    indirection so the single- and batch-program kernels share the loop.
+    ``ops``/``colidx`` are python ints (OP_NOP padded); ``operand(i)``
+    reads instruction i's threshold scalar from SMEM. Returns the (1, tile)
+    f32 0/1 mask — the same arithmetic as ``ref.eval_program`` (compare ->
+    f32, AND = product, OR = clipped sum, NOT = 1 - a), so interpret mode
+    is bit-identical to the oracle on well-formed programs.
     """
-    tile = cols.shape[1]
-    stack = jnp.zeros((max_stack, tile), jnp.float32)
-    sp = jnp.zeros((), jnp.int32)
-    for i in range(n_instr):                   # static unroll
-        op, col, val = read_instr(i)
-        vec = jax.lax.dynamic_index_in_dim(cols, col, axis=0,
-                                           keepdims=False)
-        cmps = jnp.stack([
-            (vec == val), (vec != val), (vec > val), (vec >= val),
-            (vec < val), (vec <= val)], axis=0).astype(jnp.float32)
-        cmp = jax.lax.dynamic_index_in_dim(cmps, jnp.clip(op, 0, 5), axis=0,
-                                           keepdims=False)
-        a = jax.lax.dynamic_index_in_dim(stack, jnp.maximum(sp - 1, 0),
-                                         axis=0, keepdims=False)
-        b = jax.lax.dynamic_index_in_dim(stack, jnp.maximum(sp - 2, 0),
-                                         axis=0, keepdims=False)
-        is_cmp = op < 6
-        is_and = op == 6
-        is_or = op == 7
-        is_not = op == 8
-        is_nop = op < 0
-        new_val = jnp.where(is_cmp, cmp,
-                            jnp.where(is_and, a * b,
-                                      jnp.where(is_or, jnp.clip(a + b, 0, 1),
-                                                1.0 - a)))
-        write_pos = jnp.where(is_cmp, sp, jnp.where(is_not, sp - 1, sp - 2))
-        write_pos = jnp.clip(write_pos, 0, max_stack - 1)
-        written = jax.lax.dynamic_update_index_in_dim(
-            stack, new_val, write_pos, axis=0)
-        stack = jnp.where(is_nop, stack, written)
-        sp = jnp.where(is_nop, sp,
-                       jnp.where(is_cmp, sp + 1,
-                                 jnp.where(is_not, sp, sp - 1)))
-    return jax.lax.dynamic_index_in_dim(stack, jnp.maximum(sp - 1, 0),
-                                        axis=0, keepdims=False)
+    stack = []
+    for i, (op, col) in enumerate(zip(ops, colidx)):
+        if op == OP_NOP:
+            continue
+        if op <= OP_LE:
+            row = cols_ref[col:col + 1, :]
+            stack.append(_CMPS[op](row, operand(i)).astype(jnp.float32))
+        elif op == OP_NOT:
+            stack.append(1.0 - stack.pop())
+        else:
+            b, a = stack.pop(), stack.pop()
+            stack.append(a * b if op == OP_AND
+                         else jnp.clip(a + b, 0.0, 1.0))
+    if not stack:
+        return jnp.zeros((1, cols_ref.shape[1]), jnp.float32)
+    return stack[-1]
 
 
-def _policy_scan_kernel(ops_ref, colidx_ref, operands_ref, cols_ref,
-                        mask_ref, agg_ref, *, n_instr: int, max_stack: int,
+def _policy_scan_kernel(operands_ref, cols_ref, masks_ref, rule_ref,
+                        agg_ref, *, ops_t: Program, colidx_t: Program,
                         size_col: int, blocks_col: int, valid_col: int):
-    step = pl.program_id(0)
-
-    cols = cols_ref[...]                       # (n_cols, tile) f32 in VMEM
-    tile = cols.shape[1]
-
-    mask = _eval_program_tile(
-        cols, lambda i: (ops_ref[i], colidx_ref[i], operands_ref[i]),
-        n_instr, max_stack)
-    if valid_col >= 0:
-        mask = mask * cols[valid_col]
-    mask_ref[...] = mask[None, :]
-
-    # --- fused aggregation -------------------------------------------------
-    size = cols[size_col]
-    spc = cols[blocks_col]
-    count = jnp.sum(mask)
-    volume = jnp.sum(mask * size)
-    spc_used = jnp.sum(mask * spc)
-    bucket = sum((size >= e).astype(jnp.int32) for e in _EDGE_VALS) - 1
-    bucket = jnp.clip(bucket, 0, 9)
-    iota10 = jax.lax.broadcasted_iota(jnp.int32, (10, tile), 0)
-    onehot = (bucket[None, :] == iota10).astype(jnp.float32)
-    hist = onehot @ mask                       # (10,)
-    any_match = jnp.max(mask)
-    agg = jnp.concatenate([jnp.stack([count, volume, spc_used]), hist,
-                           any_match[None]])            # (N_AGG,)
-
-    @pl.when(step == 0)
-    def _init():
-        agg_ref[...] = jnp.zeros_like(agg_ref)
-
-    prev = agg_ref[0, :]
-    acc = prev + agg
-    # any_match is a max-, not sum-, accumulator
-    agg_ref[0, :] = acc.at[N_AGG - 1].set(jnp.maximum(prev[N_AGG - 1],
-                                                      any_match))
-
-
-def policy_scan_pallas(cols: jax.Array, ops: jax.Array, colidx: jax.Array,
-                       operands: jax.Array, *, size_col: int = 0,
-                       blocks_col: int = 1, valid_col: int = -1,
-                       tile: int = 8 * LANE, max_stack: int = 8,
-                       interpret: bool = True
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """cols: (n_cols, N) f32, N % tile == 0. Returns (mask (N,), agg)."""
-    n_cols, n = cols.shape
-    assert n % tile == 0, f"N={n} must be padded to tile={tile}"
-    grid = (n // tile,)
-    n_instr = int(ops.shape[0])
-
-    kernel = functools.partial(
-        _policy_scan_kernel, n_instr=n_instr, max_stack=max_stack,
-        size_col=size_col, blocks_col=blocks_col, valid_col=valid_col)
-
-    mask, agg = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_instr,), lambda i: (0,)),       # ops
-            pl.BlockSpec((n_instr,), lambda i: (0,)),       # colidx
-            pl.BlockSpec((n_instr,), lambda i: (0,)),       # operands
-            pl.BlockSpec((n_cols, tile), lambda i: (0, i)),  # column tile
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i: (0, i)),       # mask
-            pl.BlockSpec((1, N_AGG), lambda i: (0, 0)),      # aggregates
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, N_AGG), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ops, colidx, operands, cols)
-    return mask[0], agg[0]
-
-
-def _policy_scan_batch_kernel(ops_ref, colidx_ref, operands_ref, cols_ref,
-                              masks_ref, rule_ref, agg_ref, *, n_progs: int,
-                              n_instr: int, max_stack: int, size_col: int,
-                              blocks_col: int, valid_col: int):
-    """Single-launch multi-program scan: the whole (R, P) program batch over
-    one column tile, writing an (R, tile) mask block, the fused
-    first-match-wins rule attribution, and per-program aggregates.
+    """The whole (R, P) program batch over one column tile: the (R, tile)
+    mask block, the fused first-match-wins rule attribution and the
+    per-program aggregates.
 
     Program 0 is the policy's combined criteria; programs 1..R-1 are the
-    per-rule conditions in priority order. Both loops (programs × unrolled
-    instructions) are static, so the whole matcher lowers to straight-line
-    vector selects — one grid walk over the entry table replaces R kernel
-    launches and the host-side attribution pass.
+    per-rule conditions in priority order. Every loop is static, so the
+    matcher lowers to straight-line compares and selects.
     """
     step = pl.program_id(0)
-    cols = cols_ref[...]                       # (n_cols, tile) f32 in VMEM
-    tile = cols.shape[1]
-
-    rows = []
-    for r in range(n_progs):                   # static unroll over programs
-        mask = _eval_program_tile(
-            cols, lambda i, r=r: (ops_ref[r, i], colidx_ref[r, i],
-                                  operands_ref[r, i]),
-            n_instr, max_stack)
+    tile = cols_ref.shape[1]
+    n_progs = len(ops_t)
+    for r in range(n_progs):
+        mask = _eval_program_tile(cols_ref, ops_t[r], colidx_t[r],
+                                  lambda i, r=r: operands_ref[r, i])
         if valid_col >= 0:
-            mask = mask * cols[valid_col]
-        rows.append(mask)
-    masks = jnp.stack(rows)                    # (R, tile)
-    masks_ref[...] = masks
+            mask = mask * cols_ref[valid_col:valid_col + 1, :]
+        masks_ref[r:r + 1, :] = mask
+    masks = masks_ref[...]                                 # (R, tile)
 
     # --- fused first-match-wins attribution (programs 1..R-1) -------------
-    if n_progs > 1:
-        rules = masks[1:] > 0.5                # (R-1, tile)
-        first = jnp.argmax(rules, axis=0).astype(jnp.int32)
-        att = jnp.where(jnp.any(rules, axis=0), first, -1)
-    else:
-        att = jnp.full((tile,), -1, jnp.int32)
-    rule_ref[...] = att[None, :]
+    # walk the rules from lowest priority up: the last write is the first
+    # rule that matched
+    att = jnp.full((1, tile), -1, jnp.int32)
+    for r in range(n_progs - 1, 0, -1):
+        att = jnp.where(masks[r:r + 1] > 0.5, jnp.int32(r - 1), att)
+    rule_ref[...] = att
 
     # --- fused per-program aggregation ------------------------------------
-    size = cols[size_col]
-    spc = cols[blocks_col]
-    count = jnp.sum(masks, axis=1)                         # (R,)
-    volume = jnp.sum(masks * size[None, :], axis=1)        # (R,)
-    spc_used = jnp.sum(masks * spc[None, :], axis=1)       # (R,)
+    size = cols_ref[size_col:size_col + 1, :]              # (1, tile)
+    spc = cols_ref[blocks_col:blocks_col + 1, :]
+    count = jnp.sum(masks, axis=1, keepdims=True)          # (R, 1)
+    volume = jnp.sum(masks * size, axis=1, keepdims=True)
+    spc_used = jnp.sum(masks * spc, axis=1, keepdims=True)
+    any_match = jnp.max(masks, axis=1, keepdims=True)
     bucket = sum((size >= e).astype(jnp.int32) for e in _EDGE_VALS) - 1
     bucket = jnp.clip(bucket, 0, 9)
-    iota10 = jax.lax.broadcasted_iota(jnp.int32, (10, tile), 0)
-    onehot = (bucket[None, :] == iota10).astype(jnp.float32)   # (10, tile)
-    hist = jax.lax.dot_general(masks, onehot,
-                               (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)  # (R, 10)
-    any_match = jnp.max(masks, axis=1)                     # (R,)
-    agg = jnp.concatenate([count[:, None], volume[:, None],
-                           spc_used[:, None], hist, any_match[:, None]],
-                          axis=1)                          # (R, N_AGG)
+    # one-hot over the agg slots: row _HIST0 + b is set for bucket b, so
+    # the matmul lands the histogram in its slots and zeros elsewhere
+    slot = jax.lax.broadcasted_iota(jnp.int32, (N_AGG, tile), 0)
+    onehot = (bucket + _HIST0 == slot).astype(jnp.float32)  # (N_AGG, tile)
+    hist = jax.lax.dot_general(masks, onehot, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_progs, N_AGG), 1)
+    agg = jnp.where(lane == 0, count,
+                    jnp.where(lane == 1, volume,
+                              jnp.where(lane == 2, spc_used, hist)))
 
     @pl.when(step == 0)
     def _init():
         agg_ref[...] = jnp.zeros_like(agg_ref)
 
     prev = agg_ref[...]
-    acc = prev + agg
     # any_match is a max-, not sum-, accumulator
-    agg_ref[...] = acc.at[:, N_AGG - 1].set(
-        jnp.maximum(prev[:, N_AGG - 1], any_match))
+    agg_ref[...] = jnp.where(lane == N_AGG - 1,
+                             jnp.maximum(prev, any_match), prev + agg)
 
 
-def policy_scan_batch_pallas(cols: jax.Array, ops: jax.Array,
-                             colidx: jax.Array, operands: jax.Array, *,
+def policy_scan_batch_pallas(cols: jax.Array, operands: jax.Array, *,
+                             ops_t: Program, colidx_t: Program,
                              size_col: int = 0, blocks_col: int = 1,
                              valid_col: int = -1, tile: int = 8 * LANE,
-                             max_stack: int = 8, interpret: bool = True
+                             interpret: bool = True
                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """cols: (n_cols, N) f32, N % tile == 0; ops/colidx/operands: (R, P).
+    """cols: (n_cols, N) f32, N % tile == 0; operands: (R, P) f32;
+    ``ops_t``/``colidx_t``: the static (R, P) opcode/column tuples.
 
     Returns (masks (R, N) f32, rule_idx (N,) i32, agg (R, N_AGG) f32) from a
     single kernel launch.
     """
     n_cols, n = cols.shape
     assert n % tile == 0, f"N={n} must be padded to tile={tile}"
-    n_progs, n_instr = int(ops.shape[0]), int(ops.shape[1])
-    grid = (n // tile,)
-
+    n_progs = len(ops_t)
     kernel = functools.partial(
-        _policy_scan_batch_kernel, n_progs=n_progs, n_instr=n_instr,
-        max_stack=max_stack, size_col=size_col, blocks_col=blocks_col,
-        valid_col=valid_col)
-
+        _policy_scan_kernel, ops_t=ops_t, colidx_t=colidx_t,
+        size_col=size_col, blocks_col=blocks_col, valid_col=valid_col)
     masks, rule, agg = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // tile,),
         in_specs=[
-            pl.BlockSpec((n_progs, n_instr), lambda i: (0, 0)),   # ops
-            pl.BlockSpec((n_progs, n_instr), lambda i: (0, 0)),   # colidx
-            pl.BlockSpec((n_progs, n_instr), lambda i: (0, 0)),   # operands
-            pl.BlockSpec((n_cols, tile), lambda i: (0, i)),       # columns
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # operands
+            pl.BlockSpec((n_cols, tile), lambda i: (0, i)),      # columns
         ],
         out_specs=[
-            pl.BlockSpec((n_progs, tile), lambda i: (0, i)),      # masks
-            pl.BlockSpec((1, tile), lambda i: (0, i)),            # rule idx
-            pl.BlockSpec((n_progs, N_AGG), lambda i: (0, 0)),     # aggregates
+            pl.BlockSpec((n_progs, tile), lambda i: (0, i)),     # masks
+            pl.BlockSpec((1, tile), lambda i: (0, i)),           # rule idx
+            pl.BlockSpec((n_progs, N_AGG), lambda i: (0, 0)),    # aggregates
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_progs, n), jnp.float32),
@@ -275,5 +176,6 @@ def policy_scan_batch_pallas(cols: jax.Array, ops: jax.Array,
             jax.ShapeDtypeStruct((n_progs, N_AGG), jnp.float32),
         ],
         interpret=interpret,
-    )(ops, colidx, operands, cols)
+    )(operands.astype(jnp.float32), cols)
     return masks, rule[0], agg
+

@@ -8,48 +8,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import LANE, policy_scan_batch_pallas, policy_scan_pallas
+from .kernel import LANE, policy_scan_batch_pallas
 from .ref import (N_AGG, OP_AND, OP_NOP, OP_NOT, OP_OR, aggregate_multi,
-                  policy_scan_batch_ref, policy_scan_multi_ref,
-                  policy_scan_ref)
+                  policy_scan_batch_ref, policy_scan_multi_ref)
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@partial(jax.jit, static_argnames=("size_col", "blocks_col", "valid_col",
-                                   "use_kernel", "tile"))
-def policy_scan(cols: jax.Array, ops: jax.Array, colidx: jax.Array,
-                operands: jax.Array, size_col: int = 0, blocks_col: int = 1,
-                valid_col: int = -1, use_kernel: bool = True,
-                tile: int = 8 * LANE) -> Tuple[jax.Array, jax.Array]:
+def _mesh_on_tpu(mesh) -> bool:
+    """Whether a mesh's devices are TPUs — the kernels compile for the
+    chip there and run in interpret mode everywhere else (judged from the
+    mesh itself, so a mesh of described TPU devices compiles the chip path
+    from a CPU-only host)."""
+    return mesh.devices.flat[0].platform == "tpu"
+
+
+def policy_scan(cols: jax.Array, ops, colidx, operands: jax.Array,
+                size_col: int = 0, blocks_col: int = 1, valid_col: int = -1,
+                use_kernel: bool = True, tile: int = 8 * LANE
+                ) -> Tuple[jax.Array, jax.Array]:
     """Evaluate a predicate program over a columnar table + aggregates.
 
-    cols: (n_cols, N) f32. Returns (mask (N,) f32, agg (N_AGG,) f32).
-    Rows are padded to the tile size with an all-invalid pad (mask forced 0
-    via a validity column the wrapper appends when ``valid_col`` < 0).
+    cols: (n_cols, N) f32; ops/colidx: concrete (P,) arrays (the program's
+    structure is compiled in); operands: (P,) thresholds. Returns
+    (mask (N,) f32, agg (N_AGG,) f32) — the one-program case of
+    :func:`policy_scan_batch`.
     """
-    n_cols, n = cols.shape
-    if n == 0:            # zero-row table: nothing to scan (grid would be 0)
-        return jnp.zeros((0,), jnp.float32), jnp.zeros((N_AGG,), jnp.float32)
-    pad = (-n) % tile
-    if valid_col < 0:
-        valid = jnp.ones((1, n), jnp.float32)
-        cols = jnp.concatenate([cols, valid], axis=0)
-        valid_col = n_cols
-        n_cols += 1
-    if pad:
-        cols = jnp.pad(cols, ((0, 0), (0, pad)))
-    mask, agg = policy_scan_pallas(
-        cols, ops.astype(jnp.int32), colidx.astype(jnp.int32),
-        operands.astype(jnp.float32), size_col=size_col,
-        blocks_col=blocks_col, valid_col=valid_col, tile=tile,
-        interpret=not _on_tpu()) if use_kernel else policy_scan_ref(
-        cols, ops.astype(jnp.int32), colidx.astype(jnp.int32),
-        operands.astype(jnp.float32), size_col=size_col,
-        blocks_col=blocks_col, valid_col=valid_col)
-    return mask[:n], agg
+    masks, _rule, agg = policy_scan_batch(
+        cols, np.asarray(ops)[None], np.asarray(colidx)[None],
+        jnp.asarray(operands)[None], size_col=size_col,
+        blocks_col=blocks_col, valid_col=valid_col, use_kernel=use_kernel,
+        tile=tile)
+    return masks[0], agg[0]
 
 
 @partial(jax.jit, static_argnames=("size_col", "blocks_col"))
@@ -68,24 +60,36 @@ def policy_scan_multi(cols: jax.Array, ops: jax.Array, colidx: jax.Array,
                                  size_col=size_col, blocks_col=blocks_col)
 
 
-@partial(jax.jit, static_argnames=("size_col", "blocks_col", "valid_col",
-                                   "use_kernel", "tile"))
-def policy_scan_batch(cols: jax.Array, ops: jax.Array, colidx: jax.Array,
-                      operands: jax.Array, size_col: int = 0,
-                      blocks_col: int = 1, valid_col: int = -1,
-                      use_kernel: bool = True, tile: int = 8 * LANE
+def policy_scan_batch(cols: jax.Array, ops, colidx, operands: jax.Array,
+                      size_col: int = 0, blocks_col: int = 1,
+                      valid_col: int = -1, use_kernel: bool = True,
+                      tile: int = 8 * LANE
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Single-launch batch matcher over a columnar table.
 
-    cols: (n_cols, N) f32; ops/colidx/operands: (R, P) OP_NOP-padded
-    programs (program 0 = combined criteria, 1..R-1 = per-rule conditions).
-    Returns (masks (R, N) f32, rule_idx (N,) i32, agg (R, N_AGG) f32): all
-    program masks, fused first-match-wins attribution, and per-program
-    size/blocks reductions — one kernel launch instead of R.
+    cols: (n_cols, N) f32; ops/colidx: concrete (R, P) OP_NOP-padded
+    opcode/column arrays (program 0 = combined criteria, 1..R-1 =
+    per-rule conditions), compiled in as static structure; operands:
+    (R, P) thresholds, traced. Returns (masks (R, N) f32, rule_idx (N,)
+    i32, agg (R, N_AGG) f32): all program masks, fused first-match-wins
+    attribution, and per-program size/blocks reductions — one kernel
+    launch instead of R.
     """
+    ops_t, colidx_t = _program_tuples(ops, colidx)
+    return _policy_scan_batch(
+        cols, jnp.asarray(operands, jnp.float32), ops_t=ops_t,
+        colidx_t=colidx_t, size_col=size_col, blocks_col=blocks_col,
+        valid_col=valid_col, use_kernel=use_kernel, tile=tile)
+
+
+@partial(jax.jit, static_argnames=("ops_t", "colidx_t", "size_col",
+                                   "blocks_col", "valid_col", "use_kernel",
+                                   "tile"))
+def _policy_scan_batch(cols, operands, *, ops_t, colidx_t, size_col,
+                       blocks_col, valid_col, use_kernel, tile):
     n_cols, n = cols.shape
     if n == 0:            # zero-row table: nothing to scan (grid would be 0)
-        r = ops.shape[0]
+        r = len(ops_t)
         return (jnp.zeros((r, 0), jnp.float32), jnp.zeros((0,), jnp.int32),
                 jnp.zeros((r, N_AGG), jnp.float32))
     pad = (-n) % tile
@@ -93,17 +97,17 @@ def policy_scan_batch(cols: jax.Array, ops: jax.Array, colidx: jax.Array,
         valid = jnp.ones((1, n), jnp.float32)
         cols = jnp.concatenate([cols, valid], axis=0)
         valid_col = n_cols
-        n_cols += 1
     if pad:
         cols = jnp.pad(cols, ((0, 0), (0, pad)))
-    args = (cols, ops.astype(jnp.int32), colidx.astype(jnp.int32),
-            operands.astype(jnp.float32))
     kw = dict(size_col=size_col, blocks_col=blocks_col, valid_col=valid_col)
     if use_kernel:
         masks, rule, agg = policy_scan_batch_pallas(
-            *args, tile=tile, interpret=not _on_tpu(), **kw)
+            cols, operands, ops_t=ops_t, colidx_t=colidx_t, tile=tile,
+            interpret=not _on_tpu(), **kw)
     else:
-        masks, rule, agg = policy_scan_batch_ref(*args, **kw)
+        masks, rule, agg = policy_scan_batch_ref(
+            cols, jnp.asarray(ops_t, jnp.int32),
+            jnp.asarray(colidx_t, jnp.int32), operands, **kw)
     return masks[:, :n], rule[:n], agg
 
 
@@ -111,14 +115,15 @@ def _eval_unrolled(cols: jax.Array, ops: Tuple[int, ...],
                    colidx: Tuple[int, ...], operands: jax.Array) -> jax.Array:
     """Postfix program evaluation with the *program* static.
 
-    The scan/kernel evaluators treat the program as data: every
-    instruction materializes a (6, N) comparison stack and a dynamically
-    indexed (max_stack, N) value stack — ~10 full passes over the column
-    tile per instruction, all memory bandwidth. A policy's opcode/column
-    sequence is fixed per definition though (only the *operands* move with
-    ``now``), so this path unrolls the program in Python: each instruction
-    lowers to exactly the one comparison it needs, the stack lives in
-    tracer-land, and booleans (1 byte) replace f32 masks until the end.
+    The scan oracle treats the program as data: every instruction
+    materializes a (6, N) comparison stack and a dynamically indexed
+    (max_stack, N) value stack — ~10 full passes over the column tile per
+    instruction, all memory bandwidth. A policy's opcode/column sequence
+    is fixed per definition though (only the *operands* move with
+    ``now``), so this path — like the Pallas kernel — unrolls the program
+    in Python: each instruction lowers to exactly the one comparison it
+    needs, the stack lives in tracer-land, and booleans (1 byte) replace
+    f32 masks until the end.
     Bit-identical to :func:`repro.kernels.policy_scan.ref.eval_program` on
     {0, 1} masks — differential-tested.
     """
@@ -244,7 +249,8 @@ def mesh_policy_scan_batch(global_cols: jax.Array, operands: jax.Array, *,
 
     Under ``shard_map`` each device evaluates the whole program batch over
     its local (n_cols, Rp) block — the Pallas kernel
-    (:func:`policy_scan_batch`) when ``use_kernel`` else the unrolled
+    (:func:`policy_scan_batch_pallas`, compiled for the chip when the
+    mesh's devices are TPUs) when ``use_kernel`` else the unrolled
     static-program evaluator — with masks, first-match-wins attribution
     and per-program size/blocks reductions fused on-device; the
     per-program aggregates then combine across the mesh via ``psum``
@@ -267,7 +273,6 @@ def mesh_policy_scan_batch(global_cols: jax.Array, operands: jax.Array, *,
     and the psum'd aggregates all come back visibility-filtered, exactly
     as if invisible rows were invalid.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     have_perm = perm is not None
@@ -289,11 +294,11 @@ def mesh_policy_scan_batch(global_cols: jax.Array, operands: jax.Array, *,
                 agg = jnp.zeros((len(ops_t), N_AGG), jnp.float32)
                 mask0 = masks_b[0]
         else:
-            masks, rule, agg = policy_scan_batch(
-                c, jnp.asarray(np.asarray(ops_t), jnp.int32),
-                jnp.asarray(np.asarray(colidx_t), jnp.int32), operands_,
+            masks, rule, agg = policy_scan_batch_pallas(
+                c, operands_, ops_t=ops_t, colidx_t=colidx_t,
                 size_col=size_col, blocks_col=blocks_col,
-                valid_col=valid_col, use_kernel=True, tile=tile)
+                valid_col=valid_col, tile=tile,
+                interpret=not _mesh_on_tpu(mesh))
             if bits is not None:
                 # the kernel aggregated pre-AND: fold the subject bitset
                 # into the masks and recompute the (cheap) reductions
@@ -311,14 +316,14 @@ def mesh_policy_scan_batch(global_cols: jax.Array, operands: jax.Array, *,
     args = (global_cols, operands.astype(jnp.float32))
     if have_perm:
         args = args + (perm, jnp.asarray(subject, jnp.int32))
-    # check_rep=False: the program-eval scan/argmax trips shard_map's
-    # replication checker (jax#mismatched-replication-types); the agg
-    # output IS replicated — psum/pmax above combine it across the mesh
-    return shard_map(
+    # check_vma=False: the agg output IS replicated — psum/pmax above
+    # combine it across the mesh — but the Pallas call's outputs carry no
+    # varying-axes type for the checker to prove it
+    return jax.shard_map(
         _device_scan, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P("shards"), P("shards"), P()),
-        check_rep=False,
+        check_vma=False,
     )(*args)
 
 
@@ -350,7 +355,6 @@ def mesh_column_topk(global_cols: jax.Array, *, mesh, col: int, k: int,
     bitset into the row filter — the scoped top-k ranks only rows the
     tenant may see.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     have_perm = perm is not None
@@ -371,9 +375,9 @@ def mesh_column_topk(global_cols: jax.Array, *, mesh, col: int, k: int,
     in_specs = (P("shards"),) + ((P("shards"), P()) if have_perm else ())
     args = (global_cols,) + ((perm, jnp.asarray(subject, jnp.int32))
                              if have_perm else ())
-    return shard_map(_device, mesh=mesh, in_specs=in_specs,
-                     out_specs=(P("shards"), P("shards")),
-                     check_rep=False)(*args)
+    return jax.shard_map(_device, mesh=mesh, in_specs=in_specs,
+                         out_specs=(P("shards"), P("shards")),
+                         check_vma=False)(*args)
 
 
 @partial(jax.jit, static_argnames=("mesh", "col", "ge", "valid_col",
@@ -392,7 +396,6 @@ def mesh_threshold_rows(global_cols: jax.Array, thr: jax.Array, *, mesh,
     ``perm``/``subject`` apply the same visibility AND as the top-k pass
     so both passes of a scoped query select from the same row set.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     have_perm = perm is not None
@@ -412,8 +415,8 @@ def mesh_threshold_rows(global_cols: jax.Array, thr: jax.Array, *, mesh,
     args = (global_cols, jnp.asarray(thr, jnp.float32))
     if have_perm:
         args = args + (perm, jnp.asarray(subject, jnp.int32))
-    return shard_map(_device, mesh=mesh, in_specs=in_specs,
-                     out_specs=P("shards"), check_rep=False)(*args)
+    return jax.shard_map(_device, mesh=mesh, in_specs=in_specs,
+                         out_specs=P("shards"), check_vma=False)(*args)
 
 
 @partial(jax.jit, static_argnames=("mesh", "ord_col", "type_col", "size_col",
@@ -435,7 +438,6 @@ def mesh_range_aggregate(global_cols: jax.Array, bounds: jax.Array, *, mesh,
     into the range mask — scoped ``du`` counts only rows the tenant may
     see, still in one fused pass.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     have_perm = perm is not None
@@ -461,8 +463,8 @@ def mesh_range_aggregate(global_cols: jax.Array, bounds: jax.Array, *, mesh,
     args = (global_cols, bounds.astype(jnp.float32))
     if have_perm:
         args = args + (perm, jnp.asarray(subject, jnp.int32))
-    return shard_map(_device, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(), check_rep=False)(*args)
+    return jax.shard_map(_device, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def column_stack(arrays) -> jax.Array:

@@ -13,10 +13,11 @@ The cube accumulator block (3*B, S*A) is revisited by every grid step
 (standard Pallas reduction pattern): rows [0, B) are counts, [B, 2B)
 volumes, [2B, 3B) spc_used.
 
-VMEM budget: the gid one-hot is (B, tile) f32 — with the default
-``tile=1024`` that is 4 MB at B=1024, so the op wrapper caps the group
-axis (callers with more distinct (owner, group, type, hsm) combinations
-fall back to the host groupby path).
+VMEM budget: the gid one-hots are (B, tile) f32 — with the default
+``tile=1024`` that is 4 MB each at B=1024 — so the op wrapper caps the
+group axis at the largest B that compiles for a v5e (``ops.MAX_GROUPS``;
+callers with more distinct (owner, group, type, hsm) combinations fall
+back to the host groupby path).
 """
 from __future__ import annotations
 
@@ -63,9 +64,11 @@ def _profile_cube_kernel(cols_ref, cube_ref, *, n_groups: int, gid_col: int,
     sa = sb * A_BUCKETS + ab                  # (tile,) i32
 
     # --- one-hot segment reduction through the MXU ------------------------
-    iota_b = jax.lax.broadcasted_iota(jnp.float32, (n_groups, tile), 0)
-    onehot_g = (gid[None, :] == iota_b).astype(jnp.float32) \
-        * valid[None, :]                      # (B, tile)
+    # Mosaic builds iotas of integers only: compare the (exact, small)
+    # group codes as int32
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (n_groups, tile), 0)
+    onehot_g = (gid.astype(jnp.int32)[None, :] == iota_b) \
+        .astype(jnp.float32) * valid[None, :]  # (B, tile)
     n_sa = S_BUCKETS * A_BUCKETS
     iota_sa = jax.lax.broadcasted_iota(jnp.int32, (n_sa, tile), 0)
     onehot_sa = (sa[None, :] == iota_sa).astype(jnp.float32)   # (SA, tile)

@@ -23,13 +23,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..policy_scan.ops import _mesh_on_tpu
 from .kernel import LANE, profile_cube_pallas
 from .ref import A_BUCKETS, N_MEASURES, S_BUCKETS, profile_cube_ref
 
-# The (B, tile) gid one-hot must stay within a sane VMEM budget; catalogs
-# with more distinct (owner, group, type, hsm) combinations take the host
-# groupby path (see core.profiles).
-MAX_GROUPS = 4096
+# The (B, tile) gid one-hots must fit the kernel's scoped VMEM (16 MiB on
+# a v5e): 3264 is the largest multiple of 8 that compiles for one at the
+# default tile, in the op's 7-row layout and over a full 21-row store
+# block (tests/kernels/test_tpu_compile.py). Catalogs with more distinct
+# (owner, group, type, hsm) combinations take the host groupby path (see
+# core.profiles).
+MAX_GROUPS = 3264
 
 
 def _on_tpu() -> bool:
@@ -129,7 +133,6 @@ def mesh_profile_cube(global_cols: jax.Array, *, mesh, n_groups: int,
     allocates the group axis padded) and ``Rp`` a multiple of ``tile``.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def _device(cols):
         c = cols[0]                              # (n_cols, Rp) local block
@@ -138,7 +141,7 @@ def mesh_profile_cube(global_cols: jax.Array, *, mesh, n_groups: int,
                 c, n_groups=n_groups, gid_col=gid_col, size_col=size_col,
                 blocks_col=blocks_col, age_col=size_col, valid_col=valid_col,
                 sb_col=sb_col, ab_col=ab_col, tile=tile,
-                interpret=not _on_tpu())
+                interpret=not _mesh_on_tpu(mesh))
             cube = cube.reshape(N_MEASURES, n_groups, S_BUCKETS, A_BUCKETS)
         else:
             cube = profile_cube_ref(
@@ -148,9 +151,9 @@ def mesh_profile_cube(global_cols: jax.Array, *, mesh, n_groups: int,
         combined = jax.lax.psum(cube, "shards")
         return cube.reshape(N_MEASURES, -1)[None], combined
 
-    return shard_map(_device, mesh=mesh, in_specs=(P("shards"),),
-                     out_specs=(P("shards"), P()),
-                     check_rep=False)(global_cols)
+    return jax.shard_map(_device, mesh=mesh, in_specs=(P("shards"),),
+                         out_specs=(P("shards"), P()),
+                         check_vma=False)(global_cols)
 
 
 @partial(jax.jit, static_argnames=("mesh", "n_groups", "gid_col", "size_col",
@@ -170,7 +173,6 @@ def mesh_scoped_cube(global_cols: jax.Array, perm: jax.Array,
     cubes psum into the replicated (N_MEASURES, n_groups, S, A) f32 cube.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def _device(cols, pm, sid):
         c = cols[0]                              # (n_cols, Rp) local block
@@ -187,10 +189,11 @@ def mesh_scoped_cube(global_cols: jax.Array, perm: jax.Array,
             sb_col=sb_col, ab_col=ab_col)
         return jax.lax.psum(cube, "shards")
 
-    return shard_map(_device, mesh=mesh,
-                     in_specs=(P("shards"), P("shards"), P()),
-                     out_specs=P(), check_rep=False)(
-                         global_cols, perm, jnp.asarray(subject, jnp.int32))
+    return jax.shard_map(_device, mesh=mesh,
+                         in_specs=(P("shards"), P("shards"), P()),
+                         out_specs=P(), check_vma=False)(
+                             global_cols, perm,
+                             jnp.asarray(subject, jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("mesh",))
@@ -200,10 +203,9 @@ def mesh_cube_combine(partials: jax.Array, *, mesh) -> jax.Array:
     itself (columns stay put), so a warm query after scatter-add updates
     costs one small collective."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def _device(p):
         return jax.lax.psum(p[0], "shards")
 
-    return shard_map(_device, mesh=mesh, in_specs=(P("shards"),),
-                     out_specs=P(), check_rep=False)(partials)
+    return jax.shard_map(_device, mesh=mesh, in_specs=(P("shards"),),
+                         out_specs=P(), check_vma=False)(partials)

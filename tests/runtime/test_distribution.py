@@ -130,7 +130,6 @@ def test_grad_compression_shard_map():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
-from jax.experimental.shard_map import shard_map
 from repro.optim.grad_compression import make_compressed_allreduce, init_error_state
 
 mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
@@ -139,8 +138,8 @@ rng = np.random.default_rng(0)
 g_local = jnp.asarray(rng.standard_normal((4, 64)), jnp.float32)  # per-shard grads
 err0 = jnp.zeros((4, 64), jnp.float32)
 
-@partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-         out_specs=(P("data"), P("data")))
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+         out_specs=(P("data"), P("data")), check_vma=False)
 def reduce_once(g, e):
     out, e2 = reduce_tree({"g": g}, {"g": e})
     return out["g"], e2["g"]
