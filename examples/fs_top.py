@@ -96,7 +96,7 @@ def frame(i, reg, prev_counters, dt, rep):
     print(f"ingest   {r('pipeline_events_folded'):8.0f} ev/s folded   "
           f"backlog {backlog:6d} rec   lag {lag:6.2f}s")
     print(f"refresh  {r('store_rows_scattered'):8.0f} rows/s scattered "
-          f" bytes {format_size(int(r('store_bytes_moved')))}/s   "
+          f" bytes {format_size(int(r('store_h2d_bytes')))}/s   "
           f"full uploads {tot('store_full_uploads')}")
     print(f"matching {r('store_queries'):8.0f} store queries/s   "
           f"fallbacks {tot('fallback')}   "

@@ -210,6 +210,8 @@ _GID_COL = _VALID_COL + 2                 # dense profile group id (cube)
 _SB_COL = _VALID_COL + 3                  # size-profile bucket (cube)
 _AB_COL = _VALID_COL + 4                  # age bucket as of _cube_ref (cube)
 _N_ANALYTICS = 4
+# modes of the store_h2d_bytes counter, one per kind of upload
+_H2D_MODES = ("full", "scatter", "cube", "perm", "window")
 
 # columns the host mirror serves to the planner (fids + kernel columns);
 # a policy sorting by anything else (e.g. parent_fid) cannot plan from the
@@ -240,10 +242,11 @@ def _scatter_rows(buf, rows: np.ndarray, vals: np.ndarray):
     if _SCATTER_FN is None:
         import jax
 
-        def fn(buf, rows, vals):
+        # the function's name is the program's name in a profiler trace
+        def store_scatter_rows(buf, rows, vals):
             return buf.at[0, : vals.shape[0], rows].set(vals.T)
 
-        _SCATTER_FN = jax.jit(fn, donate_argnums=(0,))
+        _SCATTER_FN = jax.jit(store_scatter_rows, donate_argnums=(0,))
     return _SCATTER_FN(buf, rows, vals)
 
 
@@ -296,10 +299,10 @@ def _pad_block(buf, pad: int):
         import jax
         import jax.numpy as jnp
 
-        def fn(buf, *, pad):
+        def store_pad_block(buf, *, pad):
             return jnp.pad(buf, ((0, 0), (0, 0), (0, pad)))
 
-        _PAD_BLOCK_FN = jax.jit(fn, static_argnames=("pad",),
+        _PAD_BLOCK_FN = jax.jit(store_pad_block, static_argnames=("pad",),
                                 donate_argnums=(0,))
     return _PAD_BLOCK_FN(buf, pad=pad)
 
@@ -315,10 +318,11 @@ def _scatter_row(buf, row: int, rows: np.ndarray, vals: np.ndarray):
     if _SCATTER_ROW_FN is None:
         import jax
 
-        def fn(buf, rows, vals, *, row):
+        def store_scatter_row(buf, rows, vals, *, row):
             return buf.at[0, row, rows].set(vals)
 
-        _SCATTER_ROW_FN = jax.jit(fn, static_argnames=("row",),
+        _SCATTER_ROW_FN = jax.jit(store_scatter_row,
+                                  static_argnames=("row",),
                                   donate_argnums=(0,))
     return _SCATTER_ROW_FN(buf, rows, vals, row=row)
 
@@ -335,10 +339,10 @@ def _cube_scatter(buf, flat: np.ndarray, vals: np.ndarray):
     if _CUBE_SCATTER_FN is None:
         import jax
 
-        def fn(buf, flat, vals):
+        def store_cube_scatter(buf, flat, vals):
             return buf[0].at[:, flat].add(vals)[None]
 
-        _CUBE_SCATTER_FN = jax.jit(fn, donate_argnums=(0,))
+        _CUBE_SCATTER_FN = jax.jit(store_cube_scatter, donate_argnums=(0,))
     return _CUBE_SCATTER_FN(buf, flat, vals)
 
 
@@ -644,6 +648,11 @@ class DeviceColumnStore:
         self.segment_repacks = 0            # stale segments re-encoded
         self.demote_races = 0               # async packs discarded (raced)
         self.device_pads = 0                # on-device re-pads (no re-upload)
+        # device->host bytes, as the match spans' d2h_bytes attribute
+        # counts them (host->device: _h2d, by mode)
+        self._d2h_bytes = self.telemetry.counter(
+            "store_d2h_bytes", help="device->host bytes of match results",
+            **self._tlabels)
         catalog.add_delta_hook(self._on_delta, batch=self._on_delta_batch)
 
     # -- analytics planes ------------------------------------------------------
@@ -908,7 +917,7 @@ class DeviceColumnStore:
         self._global = None
         self._epoch += 1
         self.full_uploads += 1
-        self._bytes_moved("full", stack.nbytes)
+        self._h2d("full", stack.nbytes)
         if self._plane_perm:
             # block capacity may differ from the old packed words: drop
             # the packed buffer (repacked from the kept vis mirror)
@@ -944,8 +953,10 @@ class DeviceColumnStore:
         if rows is None:
             group.dirty |= dirty_set
             return False                    # unseen fid: rows shifted
-        cols, present = self.catalog.gather_rows(
-            dirty.tolist(), with_strings=self._plane_reports)
+        with self.telemetry.trace("store.refresh.gather", rows=dirty.size,
+                                  **self._tlabels):
+            cols, present = self.catalog.gather_rows(
+                dirty.tolist(), with_strings=self._plane_reports)
         if not bool(present.all()):
             group.dirty |= dirty_set
             return False                    # raced a remove: restack
@@ -1006,6 +1017,7 @@ class DeviceColumnStore:
         prows, pvals = _pad_bucket(rows.astype(np.int32), vals)
         self._bufs[group.gid] = _scatter_rows(self._bufs[group.gid],
                                               prows, pvals)
+        self._h2d("scatter", prows.nbytes + pvals.nbytes)
         if self._plane_cube and cube_live:
             if len(self._cube_groups) > self._cube_bp:
                 # a delta minted more groups than the partials can hold:
@@ -1033,6 +1045,7 @@ class DeviceColumnStore:
                 pflat, pcvals = _pad_zero(flat, cvals)
                 self._cube_bufs[group.gid] = _cube_scatter(
                     self._cube_bufs[group.gid], pflat, pcvals)
+                self._h2d("cube", pflat.nbytes + pcvals.nbytes)
         if self._plane_perm:
             perm_live = (group.vis is not None
                          and self._perm_bufs is not None
@@ -1054,6 +1067,7 @@ class DeviceColumnStore:
                     pw, pv = _pad_bucket(words.astype(np.int32), wvals)
                     self._perm_bufs[group.gid] = _scatter_rows(
                         self._perm_bufs[group.gid], pw, pv)
+                    self._h2d("perm", pw.nbytes + pv.nbytes)
                     self.perm_word_scatters += 1
             else:
                 # grants ticked (or the bitset never materialized): a
@@ -1065,13 +1079,21 @@ class DeviceColumnStore:
         self._epoch += 1
         self.delta_refreshes += 1
         self.rows_scattered += int(dirty.size)
-        self._bytes_moved("scatter", vals.nbytes)
         return True
 
-    def _bytes_moved(self, mode: str, nbytes: int) -> None:
-        self.telemetry.counter(
-            "store_bytes_moved", help="host->device bytes shipped",
-            mode=mode, **self._tlabels).inc(int(nbytes))
+    def _h2d_series(self, mode: str):
+        return self.telemetry.counter(
+            "store_h2d_bytes",
+            help="host->device bytes shipped, padded, by mode: full "
+                 "uploads, row scatters, cube and permission-word "
+                 "scatters, streamed windows",
+            mode=mode, **self._tlabels)
+
+    def _h2d(self, mode: str, nbytes: int) -> None:
+        self._h2d_series(mode).inc(int(nbytes))
+
+    def _h2d_total(self) -> float:
+        return sum(self._h2d_series(m).value for m in _H2D_MODES)
 
     def _round_up(self, n: int) -> int:
         return -(-max(n, 1) // self.tile) * self.tile
@@ -1116,9 +1138,11 @@ class DeviceColumnStore:
         sibling. Placement (demote/promote under ``hbm_budget_rows``) and
         warm-segment freshness run first, so after a refresh both the
         resident blocks and the warm segments reflect the catalog."""
-        with self.telemetry.trace("store.refresh", **self._tlabels) as _sp:
+        with self.telemetry.trace("store.refresh", **self._tlabels) as _sp, \
+                self._lock:
+            h2d0 = self._h2d_total()
             stats = self._refresh_locked()
-            _sp.annotate(**stats)
+            _sp.annotate(h2d_bytes=int(self._h2d_total() - h2d0), **stats)
             return stats
 
     def _refresh_locked(self) -> Dict[str, int]:
@@ -1610,6 +1634,7 @@ class DeviceColumnStore:
         words = np.packbits(
             sub.reshape(self._perm_sp, D, rw).transpose(1, 0, 2),
             axis=2, bitorder="little").view(np.uint32)
+        self._h2d("window", words.nbytes)
         return jax.make_array_from_single_device_arrays(
             (D, self._perm_sp, rw // 32),
             NamedSharding(self.mesh, P("shards")),
@@ -1668,7 +1693,7 @@ class DeviceColumnStore:
                 if want_perm else None
             res = launch(win, pwin)
             self.windows_streamed += 1
-            self._bytes_moved("window", buf.nbytes)
+            self._h2d("window", buf.nbytes)
             if pending is not None:
                 yield self._consume_window(pending)
             pending = (base, nrows, res)
@@ -1812,8 +1837,10 @@ class DeviceColumnStore:
                 owner = grp = np.zeros(0, np.int64)
                 rank = np.zeros(0, np.int64)
             group.vis = self._vis_rows(group.spaths, owner, grp, rank)
+            words = self._pack_group(group)
             self._perm_bufs[group.gid] = jax.device_put(
-                self._pack_group(group)[None], self.devices[group.gid])
+                words[None], self.devices[group.gid])
+            self._h2d("perm", words.nbytes)
             self.perm_materializations += 1
             changed = True
         if changed:
@@ -1938,7 +1965,6 @@ class DeviceColumnStore:
                       use_kernel: Optional[bool] = None,
                       with_agg: bool = True,
                       subject: Optional[str] = None) -> MeshMatch:
-        import jax
         from ..kernels.policy_scan.ops import (_agg_dict,
                                                merge_agg_partials,
                                                mesh_policy_scan_batch)
@@ -1957,17 +1983,14 @@ class DeviceColumnStore:
             mesh = self._resident_mesh(res)
             perm = self._assemble_perm(res, mesh) if sid is not None \
                 else None
-            with self.telemetry.trace("store.match.launch",
-                                      groups=len(res), **self._tlabels):
-                mask, rule, agg = mesh_policy_scan_batch(
-                    self._assemble(res, mesh), operands, mesh=mesh,
-                    perm=perm, subject=sid, **kw)
+            mask, rule, agg = mesh_policy_scan_batch(
+                self._assemble(res, mesh), operands, mesh=mesh,
+                perm=perm, subject=sid, **kw)
             # only mask + attribution cross device→host, never the columns
             with self.telemetry.trace("store.match.combine",
                                       **self._tlabels):
-                mask_np = np.asarray(jax.device_get(mask))
-                rule_np = np.asarray(jax.device_get(rule))
-                agg_parts.append(np.asarray(jax.device_get(agg)))
+                mask_np, rule_np, agg_np = self._readback(mask, rule, agg)
+                agg_parts.append(agg_np)
             for i, g in enumerate(res):
                 idx = np.nonzero(mask_np[i, : g.rows] > 0.5)[0]
                 mirrors[g.gid] = (g.fids, g.cols)
@@ -1982,13 +2005,15 @@ class DeviceColumnStore:
             idx_parts, rule_parts = [], []
             for base, nrows, (mask, rule, agg) in self._stream_windows(
                     g, launch, want_perm=sid is not None):
-                m = np.asarray(jax.device_get(mask)).reshape(-1)[:nrows]
-                r = np.asarray(jax.device_get(rule)).reshape(-1)[:nrows]
+                got = self._readback(*((mask, rule, agg) if with_agg
+                                       else (mask, rule)))
+                m = got[0].reshape(-1)[:nrows]
+                r = got[1].reshape(-1)[:nrows]
                 hit = np.nonzero(m > 0.5)[0]
                 idx_parts.append(base + hit)
                 rule_parts.append(r[hit].astype(np.int32))
                 if with_agg:
-                    agg_parts.append(np.asarray(jax.device_get(agg)))
+                    agg_parts.append(got[2])
             dec = g.segment.columns()
             mirrors[g.gid] = (np.asarray(dec["fid"], np.int64),
                               {n: dec[n] for n in PLAN_COLUMNS})
@@ -2001,6 +2026,21 @@ class DeviceColumnStore:
         return MeshMatch(self, self._epoch, mirrors, group_idx,
                          group_rule, _agg_dict(per_rule[0], per_rule),
                          reval)
+
+    def _readback(self, *arrays) -> List[np.ndarray]:
+        """Wait for the device, then copy a match's results to the host:
+        the wait (``store.match.wait``) and the copy
+        (``store.match.readback``, its ``d2h_bytes``, and the
+        ``store_d2h_bytes`` counter) timed and counted apart."""
+        import jax
+        with self.telemetry.trace("store.match.wait", **self._tlabels):
+            jax.block_until_ready(arrays)
+        nbytes = sum(int(a.nbytes) for a in arrays)
+        with self.telemetry.trace("store.match.readback", d2h_bytes=nbytes,
+                                  **self._tlabels):
+            out = [np.asarray(jax.device_get(a)) for a in arrays]
+        self._d2h_bytes.inc(nbytes)
+        return out
 
     def scan(self, expr, now: float,
              use_kernel: Optional[bool] = None) -> Tuple[np.ndarray, dict]:
@@ -2061,6 +2101,7 @@ class DeviceColumnStore:
                     pflat, pcvals = _pad_zero(flat, cvals)
                     self._cube_bufs[group.gid] = _cube_scatter(
                         self._cube_bufs[group.gid], pflat, pcvals)
+                    self._h2d("cube", pflat.nbytes + pcvals.nbytes)
                 group.cab[due] = new_ab
                 group.cflip[due] = stamps + _FLIP_EDGES[new_ab]
                 # scatter the new age buckets into the resident block so a
@@ -2071,6 +2112,7 @@ class DeviceColumnStore:
                     new_ab[None].astype(np.float32))
                 self._bufs[group.gid] = _scatter_row(
                     self._bufs[group.gid], _AB_COL, prows, pvals[0])
+                self._h2d("scatter", prows.nbytes + pvals.nbytes)
                 moved += int(due.size)
             finite = np.isfinite(group.cflip)
             group.cmin_flip = float(group.cflip[finite].min()) \

@@ -113,7 +113,7 @@ import numpy as np
 from .catalog import Catalog, ColumnBatch
 from .changelog import ChangelogHub, ChangelogStream
 from .fidtable import FidTable as _FidTable
-from .telemetry import slug
+from .telemetry import handoff, slug
 from .policy import (AGE_ATTRS, ALWAYS, Cmp, Expr, GLOB_ATTRS, PolicyError,
                      all_of, any_of, attribute_rules, iter_exprs, parse_expr)
 from .types import Entry, FsType
@@ -200,9 +200,9 @@ class RunReport:
     # bench_tiering asserts streaming really happened from these
     tiering: dict = dataclasses.field(default_factory=dict)
     # per-run telemetry (empty when the catalog's registry is disabled):
-    # {"spans": nested span tree of the whole run — ingest/match/act
-    # children, the device store's refresh/launch/combine spans nested
-    # inside — "counters": registry counter deltas this run caused}
+    # {"spans": nested span tree of the whole run — ingest/match/plan/act
+    # children, the device store's refresh/combine spans nested inside —
+    # "counters": registry counter deltas this run caused}
     telemetry: dict = dataclasses.field(default_factory=dict)
 
 
@@ -873,10 +873,12 @@ class PolicyEngine:
         executed = 0
         plan = None
         if fids.size:
-            key = -sort_keys if policy.sort_desc else sort_keys
-            order = np.lexsort((fids, key))    # fid tie-break: total order,
-            plan = _Plan(fids=fids[order],     # identical across planners
-                         sizes=sizes[order], rule_idx=ridx[order])
+            with self.telemetry.trace("run.plan", **self._tlabels):
+                key = -sort_keys if policy.sort_desc else sort_keys
+                # fid tie-break: a total order, identical across planners
+                order = np.lexsort((fids, key))
+                plan = _Plan(fids=fids[order], sizes=sizes[order],
+                             rule_idx=ridx[order])
             budget_volume = target_volume or policy.max_volume_per_run
             budget_count = policy.max_actions_per_run
             with self.telemetry.trace("run.act", execution=execution,
@@ -1040,19 +1042,23 @@ class PolicyEngine:
         chunk = max(1, policy.batch_size)
         work: "deque[slice]" = deque(slice(i, min(i + chunk, hi))
                                      for i in range(lo, hi, chunk))
-
-        def worker() -> None:
-            while True:
-                try:
-                    sl = work.popleft()    # atomic; IndexError ends worker
-                except IndexError:
-                    return
-                self._apply_chunk(policy, plan, sl, now, report, execution)
-
         n_threads = min(max(1, policy.n_threads), len(work))
         if n_threads <= 1:
-            worker()
+            for sl in work:
+                self._apply_chunk(policy, plan, sl, now, report, execution)
             return
+        parent = handoff()              # chunk spans join the run.act span
+
+        def worker() -> None:
+            with parent:
+                while True:
+                    try:
+                        sl = work.popleft()  # atomic; IndexError ends it
+                    except IndexError:
+                        return
+                    self._apply_chunk(policy, plan, sl, now, report,
+                                      execution)
+
         threads = [threading.Thread(target=worker, daemon=True)
                    for _ in range(n_threads)]
         for t in threads:
@@ -1090,14 +1096,18 @@ class PolicyEngine:
         entries: Optional[List[Optional[Entry]]] = None
         batch: Optional[ColumnBatch] = None
         if batch_fn is None or needs_entries or execution == "batched":
-            entries = self.catalog.get_batch(fids.tolist())
+            with self.telemetry.trace("run.act.gather", rows=len(fids),
+                                      **self._tlabels):
+                entries = self.catalog.get_batch(fids.tolist())
             skipped = np.array([e is None for e in entries])
             if batch_fn is not None and not needs_entries:
                 batch = ColumnBatch.from_entries(entries,
                                                  self.catalog.strings,
                                                  self.catalog)
         else:
-            batch = self.catalog.column_batch(fids.tolist())
+            with self.telemetry.trace("run.act.gather", rows=len(fids),
+                                      **self._tlabels):
+                batch = self.catalog.column_batch(fids.tolist())
             skipped = ~batch.present
         ok = np.zeros(len(fids), dtype=bool)
         if batch_fn is not None:

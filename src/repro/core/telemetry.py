@@ -40,7 +40,8 @@ Tracing
 :meth:`MetricRegistry.trace` opens a span: wall-clock timed, nested
 per-thread (a ``trace`` inside an active trace of the same registry
 becomes a child), thread-safe (each thread owns its ambient stack;
-spans from other threads become root spans). Completed root spans land
+spans from other threads become root spans unless :func:`handoff`
+gave the thread a parent). Completed root spans land
 in a bounded ring buffer and every span close feeds the
 ``span_seconds{span=...}`` histogram. Device work is dispatched async —
 a span around a kernel launch times the *dispatch* unless the caller
@@ -48,11 +49,18 @@ opts in to a device sync: ``trace(name, sync=arrays)`` (or
 ``span.block_on(arrays)``) calls ``jax.block_until_ready`` at close and
 records the wait separately, so hot paths stay async by default.
 
+Every span also enters a ``jax.profiler.TraceAnnotation`` named
+``rbh.<span name>`` for its lifetime, on its own thread, so a profiler
+trace shows the program's spans nested on its host plane, on the same
+clock as the device's operations. (Before JAX is imported no profiler
+session can exist, and no annotation is opened.)
+
 Registry-less library code (``core.segments``, ``kernels/*/ops.py``)
 instruments through the **ambient** helpers :func:`span` and
 :func:`ambient_counter`: they attach to whatever trace is active on the
 calling thread and are no-ops (a shared null object, no allocation)
-otherwise.
+otherwise. A worker pool hands its caller's span to its threads with
+:func:`handoff`, so their spans join the caller's tree.
 
 Labels hold no wall-clock / date values — series cardinality is bounded
 by instances x enum-like label values, never by time.
@@ -61,6 +69,7 @@ from __future__ import annotations
 
 import bisect
 import re
+import sys
 import threading
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
@@ -68,7 +77,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "Span", "TextState",
     "ambient_counter", "ambient_registry", "counter_attr", "state_attr",
-    "parse_prometheus", "span", "DEFAULT_LATENCY_EDGES",
+    "handoff", "parse_prometheus", "span", "DEFAULT_LATENCY_EDGES",
 ]
 
 # log-spaced seconds: 50us .. 10s — wide enough for a host fold at 1M
@@ -76,6 +85,9 @@ __all__ = [
 DEFAULT_LATENCY_EDGES: Tuple[float, ...] = (
     50e-6, 100e-6, 250e-6, 500e-6, 1e-3, 2.5e-3, 5e-3, 10e-3, 25e-3,
     50e-3, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+
+# prefix of the profiler annotation each span opens
+PROFILER_PREFIX = "rbh."
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
 _LABEL_RE = re.compile(r"[^a-zA-Z0-9_]")
@@ -302,27 +314,42 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 _ACTIVE = threading.local()              # per-thread [(registry, span)] stack
+# children lists are plain lists, and a handed-off parent gets children
+# from several threads at once
+_ATTACH_LOCK = threading.Lock()
+
+
+def _stack() -> list:
+    stack = getattr(_ACTIVE, "stack", None)
+    if stack is None:
+        stack = _ACTIVE.stack = []
+    return stack
 
 
 class _TraceCtx:
     """Context manager produced by :meth:`MetricRegistry.trace`."""
 
-    __slots__ = ("_reg", "_span", "_root")
+    __slots__ = ("_reg", "_span", "_root", "_ann")
 
     def __init__(self, reg: "MetricRegistry", span_: Span) -> None:
         self._reg = reg
         self._span = span_
         self._root = False
+        self._ann = None
 
     def __enter__(self) -> Span:
-        stack = getattr(_ACTIVE, "stack", None)
-        if stack is None:
-            stack = _ACTIVE.stack = []
+        stack = _stack()
         if stack and stack[-1][0] is self._reg:
-            stack[-1][1].children.append(self._span)
+            with _ATTACH_LOCK:
+                stack[-1][1].children.append(self._span)
         else:
             self._root = True
         stack.append((self._reg, self._span))
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(
+                PROFILER_PREFIX + self._span.name)
+            self._ann.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> bool:
@@ -330,7 +357,30 @@ class _TraceCtx:
         assert stack and stack[-1][1] is self._span, "unbalanced trace()"
         stack.pop()
         self._span._close()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._reg._span_closed(self._span, self._root)
+        return False
+
+
+class _Handoff:
+    """Context manager produced by :func:`handoff`: makes one captured
+    ``(registry, span)`` the innermost trace of whichever thread enters
+    it. Stateless, so many worker threads may enter it at once."""
+
+    __slots__ = ("_entry",)
+
+    def __init__(self, entry: Tuple["MetricRegistry", Span]) -> None:
+        self._entry = entry
+
+    def __enter__(self) -> Span:
+        _stack().append(self._entry)
+        return self._entry[1]
+
+    def __exit__(self, *exc) -> bool:
+        stack = _ACTIVE.stack
+        assert stack and stack[-1] is self._entry, "unbalanced handoff()"
+        stack.pop()
         return False
 
 
@@ -620,6 +670,17 @@ def span(name: str, **attrs):
     if not stack:
         return _NULL_SPAN
     return stack[-1][0].trace(name, **attrs)
+
+
+def handoff():
+    """The innermost trace active on this thread, as a context manager
+    that worker threads enter: traces they open inside it attach under
+    that span instead of becoming root spans. Opens no span itself; a
+    shared no-op outside any trace."""
+    stack = getattr(_ACTIVE, "stack", None)
+    if not stack:
+        return _NULL_SPAN
+    return _Handoff(stack[-1])
 
 
 def ambient_counter(name: str, n: float = 1.0, **labels) -> None:

@@ -547,23 +547,21 @@ def match_programs(arrays, exprs, strings, now: float,
     if single_launch is None:
         single_launch = True
     if single_launch:
-        # the launch span times the async dispatch only; the device wait
-        # lands in kernel.readback where the host actually blocks
-        with _tspan("kernel.launch", programs=int(ops.shape[0])):
-            if use_kernel:
-                m, rule, agg = policy_scan_batch(
-                    kcols, jnp.asarray(ops), jnp.asarray(colidx),
-                    jnp.asarray(operands), size_col=size_col,
-                    blocks_col=blocks_col, use_kernel=True)
-            else:
-                # off-TPU oracle: the unrolled static-program evaluator
-                # (same outputs, ~an order of magnitude less memory
-                # traffic)
-                ops_t, colidx_t = _program_tuples(ops, colidx)
-                m, rule, agg = policy_scan_batch_unrolled(
-                    kcols, jnp.asarray(operands), ops_t=ops_t,
-                    colidx_t=colidx_t, size_col=size_col,
-                    blocks_col=blocks_col)
+        # the launch is async: the device wait lands in kernel.readback,
+        # where the host actually blocks
+        if use_kernel:
+            m, rule, agg = policy_scan_batch(
+                kcols, jnp.asarray(ops), jnp.asarray(colidx),
+                jnp.asarray(operands), size_col=size_col,
+                blocks_col=blocks_col, use_kernel=True)
+        else:
+            # off-TPU oracle: the unrolled static-program evaluator (same
+            # outputs, ~an order of magnitude less memory traffic)
+            ops_t, colidx_t = _program_tuples(ops, colidx)
+            m, rule, agg = policy_scan_batch_unrolled(
+                kcols, jnp.asarray(operands), ops_t=ops_t,
+                colidx_t=colidx_t, size_col=size_col,
+                blocks_col=blocks_col)
         with _tspan("kernel.readback"):
             m = np.asarray(m) > 0.5
             masks = [m[r] for r in range(m.shape[0])]
@@ -631,12 +629,9 @@ def scan_catalog(catalog, expr, now: float, use_kernel: bool = True,
                           for c in KERNEL_COLUMNS], axis=0)
     size_col = KERNEL_COLUMNS.index("size")
     blocks_col = KERNEL_COLUMNS.index("blocks")
-    with _tspan("kernel.launch"):
-        mask, agg = policy_scan(cols, jnp.asarray(ops),
-                                jnp.asarray(colidx),
-                                jnp.asarray(operands), size_col=size_col,
-                                blocks_col=blocks_col,
-                                use_kernel=use_kernel)
+    mask, agg = policy_scan(cols, jnp.asarray(ops), jnp.asarray(colidx),
+                            jnp.asarray(operands), size_col=size_col,
+                            blocks_col=blocks_col, use_kernel=use_kernel)
     with _tspan("kernel.readback"):
         mask_np = np.asarray(mask) > 0.5
         agg_np = np.asarray(agg)

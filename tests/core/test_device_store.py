@@ -442,3 +442,115 @@ def test_refresh_repads_when_group_outgrows_capacity_mid_refresh():
     ref = cat.arrays()
     ref_fids = ref["fid"][parse_expr("size > 4M").mask(ref, cat.strings, NOW)]
     assert sorted(fids.tolist()) == sorted(ref_fids.tolist())
+
+
+# -- tracing: spans, byte counts and program names ------------------------------
+
+def _spans(tree, name):
+    own = [tree] if tree["name"] == name else []
+    return own + [s for c in tree.get("children", [])
+                  for s in _spans(c, name)]
+
+
+def _traced_store_run():
+    """A warm store-backed run after a 40-row update: its report and
+    store."""
+    cat = _random_catalog(np.random.default_rng(11), 400)
+    policy = PolicyDefinition.from_config(
+        name="p", action=BatchRecorder(), scope="type == file",
+        rules=[("big", "size > 16M", {}), ("old", "last_access > 1000s", {})],
+        sort_by="atime", n_threads=1, batch_size=64, mutates=False)
+    eng = _engine_with_store(cat, policy)
+    eng.run("p", evaluator="policy_scan_mesh")
+    cat.update_fields_batch(list(range(1, 41)), size=32 << 20)
+    rep = eng.run("p", evaluator="policy_scan_mesh")
+    assert rep.evaluator == "policy_scan_mesh" and rep.matched > 64
+    return rep, eng.device_store
+
+
+def test_store_backed_run_tree_holds_the_layer_spans():
+    rep, _ = _traced_store_run()
+    tree = rep.telemetry["spans"]
+    assert [c["name"] for c in tree["children"]] == [
+        "run.ingest", "run.match", "run.plan", "run.act"]
+    [refresh] = _spans(tree, "store.refresh")
+    assert [c["name"] for c in refresh["children"]] == [
+        "store.refresh.gather"]
+    assert refresh["children"][0]["attrs"]["rows"] == 40
+    [combine] = _spans(tree, "store.match.combine")
+    assert [c["name"] for c in combine["children"]] == [
+        "store.match.wait", "store.match.readback"]
+    gathers = _spans(tree, "run.act.gather")
+    assert len(gathers) == -(-rep.matched // 64)
+    assert _spans(tree, "store.match.launch") == []
+
+
+def test_readback_and_refresh_bytes_match_the_shapes():
+    from repro.kernels.policy_scan.ref import N_AGG
+    rep, store = _traced_store_run()
+    tree = rep.telemetry["spans"]
+    counters = rep.telemetry["counters"]
+    label = f'{{store="{store._tlabels["store"]}"}}'
+    rows = store.n_devices * store._rp
+    # the engine's match (no fused aggregation) off the kernel path: a bool
+    # mask0 and an i32 rule per padded row, and (R, N_AGG) f32 aggregates
+    # for the 1 + 2 programs
+    want = rows * (1 + 4) + 3 * N_AGG * 4
+    [readback] = _spans(tree, "store.match.readback")
+    assert readback["attrs"]["d2h_bytes"] == want
+    assert counters["store_d2h_bytes" + label] == want
+    # 40 dirty rows scatter as a 64-row bucket: i32 indices and one f32
+    # value per block row; nothing else crosses the link
+    h2d = 64 * 4 + 64 * store._block_rows() * 4
+    [refresh] = _spans(tree, "store.refresh")
+    assert refresh["attrs"]["h2d_bytes"] == h2d
+    moved = {k: v for k, v in counters.items()
+             if k.startswith("store_h2d_bytes{") and v}
+    assert moved == {
+        f'store_h2d_bytes{{mode="scatter",store="{store._tlabels["store"]}"}}':
+            h2d}
+
+
+def test_full_upload_bytes_count_the_padded_blocks():
+    cat = _random_catalog(np.random.default_rng(12), 300)
+    store = DeviceColumnStore(cat, _shards_mesh())
+    with cat.telemetry.trace("outer") as outer:
+        stats = store.refresh()
+    assert stats["full"] == store.n_devices
+    # every group ships its whole (block rows, padded rows) f32 block
+    want = store.n_devices * store._block_rows() * store._rp * 4
+    [refresh] = outer.children
+    assert refresh.attrs["h2d_bytes"] == want
+    assert store._h2d_series("full").value == want
+    assert store._h2d_total() == want
+
+
+def _call_store_program(name):
+    """Run one of the store's lazily-jitted programs on a small block and
+    return (jitted function, its arguments, its static keywords)."""
+    import jax.numpy as jnp
+    from repro.core import device_store as ds
+    buf = jnp.zeros((1, 3, 256), jnp.float32)
+    rows = np.arange(64, dtype=np.int32)
+    if name == "store_scatter_rows":
+        vals = np.ones((3, 64), np.float32)
+        ds._scatter_rows(jnp.zeros_like(buf), rows, vals)
+        return ds._SCATTER_FN, (buf, rows, vals), {}
+    if name == "store_pad_block":
+        ds._pad_block(jnp.zeros_like(buf), 128)
+        return ds._PAD_BLOCK_FN, (buf,), {"pad": 128}
+    if name == "store_scatter_row":
+        vals = np.ones(64, np.float32)
+        ds._scatter_row(jnp.zeros_like(buf), 1, rows, vals)
+        return ds._SCATTER_ROW_FN, (buf, rows, vals), {"row": 1}
+    vals = np.ones((3, 64), np.float32)
+    ds._cube_scatter(jnp.zeros_like(buf), rows, vals)
+    return ds._CUBE_SCATTER_FN, (buf, rows, vals), {}
+
+
+@pytest.mark.parametrize("name", ["store_scatter_rows", "store_pad_block",
+                                  "store_scatter_row", "store_cube_scatter"])
+def test_store_programs_carry_their_names(name):
+    fn, args, kw = _call_store_program(name)
+    text = fn.lower(*args, **kw).as_text()
+    assert f"module @jit_{name} " in text

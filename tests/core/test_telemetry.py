@@ -315,6 +315,60 @@ def test_run_report_carries_span_tree_and_counter_deltas():
     assert rep2.telemetry == {}
 
 
+def test_worker_thread_spans_join_the_run_tree():
+    """With several action threads, each chunk's catalog read lands under
+    ``run.act``, once per chunk, and none becomes an orphan root span."""
+    fs, _ = _fs(30)
+    cat = Catalog()
+    Scanner(fs, cat).scan()
+    eng = PolicyEngine(cat, clock=lambda: 2e9)
+    eng.register(PolicyDefinition.from_config(
+        "p", lambda e, params: True, scope="type == file",
+        evaluator="numpy", mutates=False, n_threads=4, batch_size=4))
+    rep = eng.run("p", matching="full")
+    assert rep.matched == 30
+    tree = rep.telemetry["spans"]
+    [act] = [c for c in tree["children"] if c["name"] == "run.act"]
+    gathers = [c for c in act["children"] if c["name"] == "run.act.gather"]
+    assert len(gathers) == -(-rep.matched // 4)
+    assert sum(g["attrs"]["rows"] for g in gathers) == rep.matched
+    assert cat.telemetry.spans("run.act.gather") == []
+
+
+def _annotations(trace_dir):
+    """The program's profiler annotations in a recorded trace:
+    ``{name: (start_ns, end_ns)}``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    [path] = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                    "*", "*.xplane.pb"))
+    return {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("rbh.")}
+
+
+def test_spans_appear_nested_in_the_profiler_trace(tmp_path):
+    import jax
+    reg = MetricRegistry()
+    off = MetricRegistry(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with reg.trace("outer"):
+            with span("inner"):
+                pass
+        with off.trace("disabled"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    ann = _annotations(tmp_path)
+    assert set(ann) == {"rbh.outer", "rbh.inner"}
+    (o0, o1), (i0, i1) = ann["rbh.outer"], ann["rbh.inner"]
+    assert o0 <= i0 < i1 <= o1
+
+
 # -- alerts (satellite: persistent handle + alerts_fired) ----------------------
 def test_alert_log_persistent_handle_and_counter(tmp_path):
     fs, proj = _fs(5)
