@@ -954,9 +954,9 @@ class DeviceColumnStore:
             group.dirty |= dirty_set
             return False                    # unseen fid: rows shifted
         with self.telemetry.trace("store.refresh.gather", rows=dirty.size,
-                                  **self._tlabels):
+                                  rows_dict=0, **self._tlabels):
             cols, present = self.catalog.gather_rows(
-                dirty.tolist(), with_strings=self._plane_reports)
+                dirty, with_strings=self._plane_reports)
         if not bool(present.all()):
             group.dirty |= dirty_set
             return False                    # raced a remove: restack

@@ -752,7 +752,7 @@ class PolicyEngine:
         fids, sizes, sort_keys, rule_idx = state.plan_arrays()
         if extra is not None and fids.size:
             ebatch = self.catalog.column_batch(
-                fids.tolist(), with_strings=_uses_globs(extra))
+                fids, with_strings=_uses_globs(extra))
             emask = extra.mask(ebatch.cols, self.catalog.strings, now) \
                 & ebatch.present
             fids, sizes = fids[emask], sizes[emask]
@@ -1097,8 +1097,8 @@ class PolicyEngine:
         batch: Optional[ColumnBatch] = None
         if batch_fn is None or needs_entries or execution == "batched":
             with self.telemetry.trace("run.act.gather", rows=len(fids),
-                                      **self._tlabels):
-                entries = self.catalog.get_batch(fids.tolist())
+                                      rows_dict=0, **self._tlabels):
+                entries = self.catalog.get_batch(fids)
             skipped = np.array([e is None for e in entries])
             if batch_fn is not None and not needs_entries:
                 batch = ColumnBatch.from_entries(entries,
@@ -1106,8 +1106,8 @@ class PolicyEngine:
                                                  self.catalog)
         else:
             with self.telemetry.trace("run.act.gather", rows=len(fids),
-                                      **self._tlabels):
-                batch = self.catalog.column_batch(fids.tolist())
+                                      rows_dict=0, **self._tlabels):
+                batch = self.catalog.column_batch(fids)
             skipped = ~batch.present
         ok = np.zeros(len(fids), dtype=bool)
         if batch_fn is not None:

@@ -56,10 +56,10 @@ clock as the device's operations. (Before JAX is imported no profiler
 session can exist, and no annotation is opened.)
 
 Registry-less library code (``core.segments``, ``kernels/*/ops.py``)
-instruments through the **ambient** helpers :func:`span` and
-:func:`ambient_counter`: they attach to whatever trace is active on the
-calling thread and are no-ops (a shared null object, no allocation)
-otherwise. A worker pool hands its caller's span to its threads with
+instruments through the **ambient** helpers :func:`span`,
+:func:`ambient_counter` and :func:`ambient_tally`: they attach to
+whatever trace is active on the calling thread and are no-ops (a shared
+null object, no allocation) otherwise. A worker pool hands its caller's span to its threads with
 :func:`handoff`, so their spans join the caller's tree.
 
 Labels hold no wall-clock / date values — series cardinality is bounded
@@ -76,7 +76,8 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "Span", "TextState",
-    "ambient_counter", "ambient_registry", "counter_attr", "state_attr",
+    "ambient_counter", "ambient_registry", "ambient_tally", "counter_attr",
+    "state_attr",
     "handoff", "parse_prometheus", "span", "DEFAULT_LATENCY_EDGES",
 ]
 
@@ -689,6 +690,20 @@ def ambient_counter(name: str, n: float = 1.0, **labels) -> None:
     reg = ambient_registry()
     if reg is not None:
         reg.counter(name, **labels).inc(n)
+
+
+def ambient_tally(**counts: int) -> None:
+    """Add each count to the same-named attribute of the innermost span
+    active on this thread (a missing attribute starts at 0); a no-op
+    outside any trace. Lets registry-less code report per-call numbers
+    (``CatalogShard``'s ``rows_dict``) to whichever span wraps the call."""
+    stack = getattr(_ACTIVE, "stack", None)
+    if not stack:
+        return
+    attrs = stack[-1][1].attrs
+    with _ATTACH_LOCK:              # a handed-off span is shared by threads
+        for name, n in counts.items():
+            attrs[name] = attrs.get(name, 0) + n
 
 
 # -- compatibility descriptors -------------------------------------------------
