@@ -3,7 +3,7 @@
 This is the one module that calls the program's set-up API: it loads the
 benchmark's arrays into a ``Catalog`` through ``upsert_batch``, puts the
 catalog on the device in a ``DeviceColumnStore``, turns on the planes the
-configuration names, and registers the configuration's policy and
+configuration names, and registers the configuration's policies and
 subjects.
 """
 from __future__ import annotations
@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 import sys
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -44,6 +44,39 @@ class Recorder:
         return (np.concatenate([f for f, _ in calls]),
                 np.concatenate([np.full(f.size, r, np.int64)
                                 for f, r in calls]))
+
+
+def policies(cfg: dict) -> Dict[str, dict]:
+    """The configuration's policies by name: its ``policies`` list, or its
+    one ``policy`` (none where that is null or absent)."""
+    if "policy" in cfg and "policies" in cfg:
+        raise ValueError("a configuration holds 'policy' or 'policies', "
+                         "not both")
+    pols = cfg.get("policies")
+    if pols is None:
+        pols = [cfg["policy"]] if cfg.get("policy") else []
+    out: Dict[str, dict] = {}
+    for pol in pols:
+        if pol["name"] in out:
+            raise ValueError(f"two policies are named {pol['name']!r}")
+        out[pol["name"]] = pol
+    return out
+
+
+def request_policy(cfg: dict, req: dict) -> dict:
+    """The policy a ``policy_run`` request runs: the one it names, or the
+    configuration's only policy where it names none."""
+    pols = policies(cfg)
+    name = req.get("policy")
+    if name is None:
+        if len(pols) != 1:
+            raise ValueError(f"a policy_run names no policy, and the "
+                             f"configuration has {len(pols)}")
+        return next(iter(pols.values()))
+    if name not in pols:
+        raise ValueError(f"unknown policy {name!r}; known: "
+                         f"{', '.join(sorted(pols))}")
+    return pols[name]
 
 
 def subjects(cfg: dict) -> List[dict]:
@@ -108,7 +141,7 @@ def load_catalog(st: CatalogState, n_shards: int):
 
 
 def build(cfg: dict, st: CatalogState, n_devices: int) -> Deployment:
-    """Catalog, device store, planes, policy and subjects; starts the
+    """Catalog, device store, planes, policies and subjects; starts the
     catalog's upload to the device (the warm-up waits for it)."""
     from repro.core import DeviceColumnStore, GrantTable, PolicyDefinition, \
         PolicyEngine
@@ -143,16 +176,19 @@ def build(cfg: dict, st: CatalogState, n_devices: int) -> Deployment:
         if grants is not None:
             cube.attach_grants(grants)
     engine = recorder = None
-    pol = cfg.get("policy")
-    if pol:
+    pols = policies(cfg)
+    if pols:
+        # one recorder serves every policy: runs are sequential and each
+        # run drains it
         recorder = Recorder()
         engine = PolicyEngine(cat, clock=clock)
-        engine.register(PolicyDefinition.from_config(
-            name=pol["name"], action=recorder, scope=pol["scope"],
-            rules=[(name, cond, {"rule": r})
-                   for r, (name, cond) in enumerate(pol["rules"])],
-            sort_by=pol["sort_by"], mutates=pol["mutates"],
-            batch_size=pol["batch_size"]))
+        for pol in pols.values():
+            engine.register(PolicyDefinition.from_config(
+                name=pol["name"], action=recorder, scope=pol["scope"],
+                rules=[(name, cond, {"rule": r})
+                       for r, (name, cond) in enumerate(pol["rules"])],
+                sort_by=pol["sort_by"], mutates=pol["mutates"],
+                batch_size=pol["batch_size"]))
         engine.attach_device_store(store)
     t0 = time.perf_counter()
     store.refresh()

@@ -2,7 +2,8 @@
 parameters) and yields the run's operations, all drawn from ``--seed``.
 
 Two loops exist. ``closed`` repeats the file's ``step`` (an untimed churn
-commit, then timed operations) back to back until the window closes.
+commit, then timed operations) back to back until the window closes; a
+``policy_run`` step may name one of the configuration's policies.
 ``open`` sends queries on a schedule whatever the system does: Poisson
 arrivals at ``rate_per_s``, each query's kind from ``mix`` and its subject
 from a Zipf law over the configuration's subjects, with churn batches
@@ -79,7 +80,10 @@ class Traffic:
             if op["op"] == "churn":
                 out.append(Event(0.0, churn=self.churn.batch()))
             else:
-                out.append(Event(0.0, request={"op": op["op"]}))
+                req = {"op": op["op"]}
+                if "policy" in op:
+                    req["policy"] = op["policy"]
+                out.append(Event(0.0, request=req))
         return out
 
     # -- open loop ------------------------------------------------------------

@@ -20,7 +20,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import data, ops, roofline
+from . import data, deploy, ops, roofline
 from .tracing import Tracer, TraceSummary, annotate
 from .generator import Event, Traffic
 
@@ -58,6 +58,7 @@ class RunRecord:
     trace: Optional[TraceSummary]
     peaks: Optional[dict]
     policy_bytes: List[int]     # roofline bytes of each policy run
+    compiles_in_window: int = 0  # backend compiles inside the window
 
     def of(self, *kinds: str) -> List[OpRecord]:
         return [r for r in self.ops if r.op in kinds and r.error is None]
@@ -222,7 +223,8 @@ def open_window(dep, events: List[Event], seconds: float, drain_s: float,
 
 def sample(records: List[OpRecord], n: int, seed: int) -> List[int]:
     """Indices of the answers to compare: ``n`` drawn from the seed, at
-    least one of each kind, and the last."""
+    least one of each kind (each op, and each policy a request names),
+    and the last."""
     ok = [i for i, r in enumerate(records) if r.error is None]
     if not ok:
         return []
@@ -231,8 +233,9 @@ def sample(records: List[OpRecord], n: int, seed: int) -> List[int]:
     pick = set(order[:n])
     seen = set()
     for i in order:
-        if records[i].op not in seen:
-            seen.add(records[i].op)
+        kind = (records[i].op, records[i].request.get("policy"))
+        if kind not in seen:
+            seen.add(kind)
             pick.add(i)
     pick.add(ok[-1])
     return sorted(pick)
@@ -308,7 +311,6 @@ def prepare(workload: str, seed: int, *, require_tpu: bool = True,
     say(compile_cache=enable_compile_cache())
     counter = CompileCounter()
 
-    from . import deploy
     n = entries or cfg["entries"]
     t0 = time.perf_counter()
     st0 = data.generate(cfg["catalog"], n, seed)
@@ -382,11 +384,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         cache_hits_in_window=c1[2] - c0[2], compiles_total=c1[0],
         compile_s_total=c1[1], memory_peak_bytes=peak)
 
-    pol = cfg.get("policy")
-    per_row = roofline.row_bytes(st0, pol) if pol else 0
-    policy_bytes = [roofline.match_bytes(st0.n, per_row, r.answer[0].size)
-                    for r in records if r.op == "policy_run"
-                    and r.error is None]
+    per_row = {name: roofline.row_bytes(st0, pol)
+               for name, pol in deploy.policies(cfg).items()}
+    policy_bytes = [roofline.match_bytes(
+        st0.n, per_row[deploy.request_policy(cfg, r.request)["name"]],
+        r.answer[0].size) for r in records
+        if r.op == "policy_run" and r.error is None]
     dep = cell.dep = None              # free the program's state first
     gc.collect()
 
@@ -408,7 +411,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         # same rule, with no failed request or fallback of its own
         extra[p] = dict(c, correct=judge(c, limits, 0, 0)[1])
     rec = RunRecord(workload, cfg, tcfg, setup_s, window_s, records,
-                    summary, peaks, policy_bytes)
+                    summary, peaks, policy_bytes, c1[0] - c0[0])
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in cell_metrics(bench, workload, kind):

@@ -17,6 +17,7 @@ import numpy as np
 
 from . import reference as ref
 from .data import CatalogState
+from .deploy import request_policy
 
 _FID = re.compile(r"f(\d+)$")
 
@@ -100,7 +101,7 @@ MAKERS: Dict[str, Callable] = {"du": make_du, "find": make_find,
 # -- run ----------------------------------------------------------------------
 
 def run_policy(dep, req: dict) -> dict:
-    pol = dep.cfg["policy"]
+    pol = request_policy(dep.cfg, req)
     rep = dep.engine.run(pol["name"], evaluator=pol["evaluator"],
                          matching=pol["matching"])
     fids, rules = dep.recorder.drain()
@@ -155,7 +156,7 @@ def reference(st: CatalogState, cfg: dict, req: dict,
     """The reference answer of one request, in the normalized form."""
     op = req["op"]
     if op == "policy_run":
-        return ref.plan(st, cfg["policy"], precision)
+        return ref.plan(st, request_policy(cfg, req), precision)
     vis = ref.visible(st, req.get("subject"))
     if op == "du":
         return ref.du(st, vis, req["path"], precision)

@@ -4,8 +4,8 @@ the benchmark's own arrays (:class:`bench.data.CatalogState`).
 It imports nothing of the program and takes nothing the program made. It
 has its own small parser for the criteria strings the configurations and
 traffic use (``and``/``or``/``not``, parentheses, comparisons of size,
-blocks, ages, type, owner and group), and reads paths by their
-components, as the path format of the configuration lays them out.
+blocks, ages, type, owner, group and HSM state), and reads paths by
+their components, as the path format of the configuration lays them out.
 
 ``precision="bf16"`` is the control: every size, block count, time and
 threshold is rounded to bfloat16 first and the rest computed exactly, the
@@ -25,6 +25,10 @@ _UNITS = {"K": 1 << 10, "M": 1 << 20, "G": 1 << 30, "T": 1 << 40,
 _DURATIONS = (("min", 60), ("sec", 1), ("s", 1), ("m", 60), ("h", 3600),
               ("d", 86400), ("w", 7 * 86400), ("y", 365 * 86400))
 _AGE = {"last_access": "atime", "last_mod": "mtime"}
+# Lustre-HSM states by the code ``bench/data.py`` draws for each
+# (``hsm_state_p`` gives their shares in this order)
+_HSM = ("none", "dirty", "archiving", "archived", "released", "restoring",
+        "lost")
 _TOKEN = re.compile(r"\s*(?:(\()|(\))|(==|!=|>=|<=|>|<)|'([^']*)'|([\w.]+))")
 
 
@@ -161,6 +165,11 @@ def mask(expr, st: CatalogState, precision: str = "f32") -> np.ndarray:
     if attr in ("owner", "group"):
         code = _index(value, "user" if attr == "owner" else "grp")
         hit = getattr(st, attr) == code
+        return hit if op == "==" else ~hit
+    if attr == "hsm_state":
+        if op not in ("==", "!=") or value.lower() not in _HSM:
+            raise ValueError(f"the reference has no hsm_state {op} {value}")
+        hit = st.hsm == _HSM.index(value.lower())
         return hit if op == "==" else ~hit
     raise ValueError(f"the reference has no attribute {attr!r}")
 

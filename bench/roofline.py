@@ -24,7 +24,7 @@ _INTS = {1: (np.uint8, np.int8), 2: (np.uint16, np.int16),
 _FLOATS = {2: np.float16, 4: np.float32, 8: np.float64}
 _COLUMN = {"size": "size", "blocks": "blocks", "last_access": "atime",
            "last_mod": "mtime", "type": "is_dir", "owner": "owner",
-           "group": "group"}
+           "group": "group", "hsm_state": "hsm"}
 
 
 def width(values: np.ndarray) -> int:

@@ -5,7 +5,10 @@ other test modules import the top-level one by that name.)
 
 A cell held out of ``BENCHMARK.json`` (``held_cells.json`` beside this
 file holds its entries) runs from a root whose ``BENCHMARK.json`` adds
-them, so its files stay tested until it is added back."""
+them, so its files stay tested until it is added back. So does a test
+fixture's cell (``fixtures/cells.json``), whose configuration and traffic
+files (``fixtures/configs``, ``fixtures/traffic``) that root's ``bench/``
+holds beside the benchmark's own."""
 import json
 import os
 import sys
@@ -18,6 +21,7 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 HELD = os.path.join(os.path.dirname(__file__), "held_cells.json")
+FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 
 
 @pytest.fixture
@@ -45,16 +49,25 @@ def tiny_run(workload, seed=20240611, seconds=None, trace=False,
 
 
 def cell_root(workload, tmp):
-    """The repo root, or for a held cell ``tmp`` made into a root whose
-    ``BENCHMARK.json`` holds it."""
+    """The repo root, or for a held or fixture cell ``tmp`` made into a
+    root whose ``BENCHMARK.json`` holds it."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     if any(w["name"] == workload for w in bench["workloads"]):
         return Path(ROOT)
-    with open(HELD) as f:
-        held = json.load(f)
-    for key, entries in held.items():
-        bench[key] = bench[key] + entries
+    for path in (HELD, os.path.join(FIXTURES, "cells.json")):
+        with open(path) as f:
+            for key, entries in json.load(f).items():
+                bench[key] = bench[key] + entries
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
-    (tmp / "bench").symlink_to(os.path.join(ROOT, "bench"))
+    src = Path(ROOT, "bench")
+    (tmp / "bench").mkdir()
+    for p in src.iterdir():
+        if p.name not in ("configs", "traffic"):
+            (tmp / "bench" / p.name).symlink_to(p)
+    for sub in ("configs", "traffic"):
+        (tmp / "bench" / sub).mkdir()
+        for f in [*(src / sub).glob("*.json"),
+                  *Path(FIXTURES, sub).glob("*.json")]:
+            (tmp / "bench" / sub / f.name).symlink_to(f)
     return tmp
