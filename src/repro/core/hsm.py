@@ -25,7 +25,13 @@ from .types import Entry, FsType, HsmState
 
 
 class HsmCoordinator:
-    """Wires archive/release policies between a LustreSim and its HSM."""
+    """Wires archive/release policies between a LustreSim and its HSM.
+
+    Both policies match on the engine's attached
+    :class:`~repro.core.device_store.DeviceColumnStore` (the
+    ``policy_scan_mesh`` evaluator) whenever one is attached, and on numpy
+    otherwise; the release's per-OST restriction (``ost_idx``) is a kernel
+    column, so a watermark-triggered release stays on the device too."""
 
     def __init__(self, fs, catalog: Catalog, engine: PolicyEngine,
                  archive_age: str = "0s", archive_id: int = 1,
@@ -67,7 +73,7 @@ class HsmCoordinator:
             rules=[("archive_candidates",
                     f"(hsm_state == none or hsm_state == dirty) "
                     f"and last_mod >= {archive_age}", {})],
-            sort_by="mtime",
+            sort_by="mtime", evaluator=None,
         ))
 
         # -- release policy: archived files, LRU by atime, targeted per OST
@@ -98,7 +104,7 @@ class HsmCoordinator:
             name="hsm_release", action=do_release,
             scope="type == file",
             rules=[("release_candidates", "hsm_state == archived", {})],
-            sort_by="atime",
+            sort_by="atime", evaluator=None,
         ))
 
         def ost_usage():

@@ -155,7 +155,9 @@ class PolicyDefinition:
     n_threads: int = 1
     dry_run: bool = False
     batch_size: int = 512           # entries per execution chunk
-    evaluator: str = "numpy"        # default matching backend
+    # default matching backend; None: the attached device store's mesh
+    # (``policy_scan_mesh``), or numpy where no store is attached
+    evaluator: Optional[str] = "numpy"
     # whether the action mutates entries (purge/archive/...): actioned fids
     # are then re-marked dirty so incremental state re-observes them. Pure
     # observer actions (tagging nothing, reporting) may set False to keep
@@ -836,6 +838,7 @@ class PolicyEngine:
                                       extra_criteria, target_volume,
                                       trigger, evaluator, execution,
                                       matching)
+            self._count_rows(report)
         report.elapsed = time.perf_counter() - t0
         if self.telemetry.enabled:
             c1 = self.telemetry.counter_values()
@@ -873,7 +876,8 @@ class PolicyEngine:
         executed = 0
         plan = None
         if fids.size:
-            with self.telemetry.trace("run.plan", **self._tlabels):
+            with self.telemetry.trace("run.plan", rows=int(fids.size),
+                                      **self._tlabels):
                 key = -sort_keys if policy.sort_desc else sort_keys
                 # fid tie-break: a total order, identical across planners
                 order = np.lexsort((fids, key))
@@ -898,6 +902,16 @@ class PolicyEngine:
                 st.note_touched(acted)
         return report
 
+    def _count_rows(self, report: RunReport) -> None:
+        """Add a finished run's rows to ``policy_rows{policy,stage}``:
+        ``matched`` rows and ``actioned`` ones (those whose action
+        succeeded), so a scrape splits a mix of policies by policy."""
+        for stage, n in (("matched", report.matched),
+                         ("actioned", report.succeeded)):
+            self.telemetry.counter(
+                "policy_rows", help="rows of policy runs, by stage",
+                policy=report.policy, stage=stage, **self._tlabels).inc(n)
+
     def _record_fallback(self, reason: str) -> None:
         """Mirror a ``RunReport.fallback_reason`` entry into the registry
         as ``fallback{stage=,reason=}`` — the stage is the downgrade edge
@@ -910,6 +924,16 @@ class PolicyEngine:
             stage=stage.strip(), reason=slug(cause.strip() or "unknown"),
             **self._tlabels).inc()
 
+    def _backend(self, policy: PolicyDefinition,
+                 evaluator: Optional[str]) -> str:
+        """The evaluator a run asks for: its override, else the policy's
+        own, else the device store's mesh where one is attached."""
+        want = evaluator or policy.evaluator
+        if want is None:
+            want = "numpy" if self.device_store is None \
+                else "policy_scan_mesh"
+        return want
+
     def _match_phase(self, policy: PolicyDefinition, state, mode: str,
                      extra_criteria: Optional[Expr], now: float,
                      evaluator: Optional[str]):
@@ -918,11 +942,11 @@ class PolicyEngine:
         fallback_reason, tiering_deltas)."""
         fallback = ""
         tiering: dict = {}
+        want = self._backend(policy, evaluator)
         if mode == "incremental":
             fids, sizes, sort_keys, ridx, reval = self._match_incremental(
                 policy, state, extra_criteria, now)
             used_eval = "numpy"
-            want = evaluator or policy.evaluator
             if want != "numpy":
                 # not a degradation — the cached match table beat a full
                 # scan on ANY backend — but still recorded so callers
@@ -932,7 +956,6 @@ class PolicyEngine:
                             "exercise the evaluator)")
                 self._record_fallback(fallback)
         else:
-            want = evaluator or policy.evaluator
             mesh_done = False
             if want == "policy_scan_mesh":
                 # the mesh full scan primes the incremental cache without

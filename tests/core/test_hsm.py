@@ -88,3 +88,74 @@ def test_disaster_recovery_rebuild(fake_clock):
     n = coord.rebuild_catalog()
     assert n == fs.count()
     assert len(cat2) == fs.count()
+
+
+class _Clock:
+    def __init__(self):
+        self.t = 1_000_000.0
+
+    def __call__(self):
+        return self.t
+
+    def advance(self, dt):
+        self.t += dt
+
+
+def _hsm_cycle(store):
+    """An archive pass then a watermark release on a small file system,
+    matched with the catalog on a device store or not: the fs calls in
+    order, every file's resulting HSM state, and the run reports."""
+    from repro.core import DeviceColumnStore
+    from repro.launch.mesh import make_shards_mesh
+    clock = _Clock()
+    fs = LustreSim(n_osts=4, ost_capacity=60_000, hsm=HsmBackend(),
+                   clock=clock)
+    d = fs.mkdir(fs.root_fid(), "data")
+    fids = []
+    for i in range(40):
+        clock.advance(3)
+        f = fs.create(d, f"f{i}", owner="u")
+        fs.write(f, 1000 * (i % 7 + 1))
+        fids.append(f)
+    for f in fids[::-3]:                 # LRU order differs from mtime order
+        clock.advance(2)
+        fs.read(f)
+    cat = Catalog(n_shards=4)
+    Scanner(fs, cat).scan()
+    eng = PolicyEngine(cat, clock=clock)
+    if store:
+        eng.attach_device_store(DeviceColumnStore(cat, make_shards_mesh()))
+    calls = []
+    for op in ("hsm_archive", "hsm_release"):
+        def logged(fid, *a, _op=op, _fn=getattr(fs, op), **kw):
+            calls.append((_op, fid))
+            return _fn(fid, *a, **kw)
+        setattr(fs, op, logged)
+    coord = HsmCoordinator(fs, cat, eng, archive_age="40s", high_wm=50.0,
+                           low_wm=20.0)
+    reports = [coord.archive_pass()]
+    clock.advance(100)
+    fs.write(fids[0], 500)               # archived, then dirty again
+    cat.update_fields(fids[0], hsm_state=HsmState.DIRTY)
+    reports += coord.space_check()
+    states = {f: (cat.get(f).hsm_state, fs.stat(f).hsm_state) for f in fids}
+    return calls, states, reports
+
+
+def test_store_attached_coordinator_matches_on_the_device():
+    host = _hsm_cycle(store=False)
+    dev = _hsm_cycle(store=True)
+    calls, states, reports = dev
+    assert [r.evaluator for r in host[2]] == ["numpy"] * len(host[2])
+    assert [r.evaluator for r in reports] == \
+        ["policy_scan_mesh"] * len(reports)
+    assert all(r.fallback_reason == "" for r in host[2] + reports)
+    # an archive pass, then a release per OST over its high watermark
+    assert len(reports) > 2 and all(r.trigger.startswith("watermark:")
+                                     for r in reports[1:])
+    assert {op for op, _ in calls} == {"hsm_archive", "hsm_release"}
+    assert 0 < reports[0].succeeded < 40    # the newest files are too young
+    assert calls == host[0]
+    assert states == host[1]
+    assert [(r.matched, r.succeeded, r.volume) for r in reports] == \
+        [(r.matched, r.succeeded, r.volume) for r in host[2]]

@@ -335,6 +335,55 @@ def test_worker_thread_spans_join_the_run_tree():
     assert cat.telemetry.spans("run.act.gather") == []
 
 
+def _rows_series(engine, policy, stage):
+    return (f'policy_rows{{engine="{engine}",policy="{policy}",'
+            f'stage="{stage}"}}')
+
+
+def test_policy_rows_count_each_policy_apart():
+    """``policy_rows{policy,stage}`` adds every run's matched rows and its
+    actioned (succeeded) rows under the run's own policy."""
+    fs, _ = _fs(30)
+    cat = Catalog()
+    Scanner(fs, cat).scan()
+    eng = PolicyEngine(cat, clock=lambda: 2e9)
+    eng.register(PolicyDefinition.from_config(
+        "big", lambda e, params: e.size % 200 == 0,   # half the actions fail
+        scope="type == file", rules=[("big", "size > 1000", {})],
+        evaluator="numpy", mutates=False, batch_size=4))
+    eng.register(PolicyDefinition.from_config(
+        "all", lambda e, params: True, scope="type == file",
+        evaluator="numpy", mutates=False))
+    reps = [eng.run(p, matching="full") for p in ("big", "all", "big")]
+    assert [r.matched for r in reps] == [20, 30, 20]
+    assert [r.succeeded for r in reps] == [10, 30, 10]
+    engine = eng._tlabels["engine"]
+    for r in reps:
+        assert r.telemetry["counters"][
+            _rows_series(engine, r.policy, "matched")] == r.matched
+        assert r.telemetry["counters"][
+            _rows_series(engine, r.policy, "actioned")] == r.succeeded
+    values = cat.telemetry.counter_values()
+    for policy in ("big", "all"):
+        mine = [r for r in reps if r.policy == policy]
+        assert values[_rows_series(engine, policy, "matched")] == \
+            sum(r.matched for r in mine)
+        assert values[_rows_series(engine, policy, "actioned")] == \
+            sum(r.succeeded for r in mine)
+    prom = parse_prometheus(cat.telemetry.render_prometheus())
+    assert prom[_rows_series(engine, "big", "actioned")] == 20
+
+
+def test_run_plan_span_carries_the_rows_planned():
+    fs, _ = _fs(30)
+    cat = Catalog()
+    eng = _engine(fs, cat, "numpy")
+    rep = eng.run("p", matching="full")
+    [plan] = [c for c in rep.telemetry["spans"]["children"]
+              if c["name"] == "run.plan"]
+    assert plan["attrs"]["rows"] == rep.matched == 30
+
+
 def _annotations(trace_dir):
     """The program's profiler annotations in a recorded trace:
     ``{name: (start_ns, end_ns)}``."""
